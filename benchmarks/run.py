@@ -37,6 +37,8 @@ def main() -> None:
                              "emit one (default: bench-specific name)")
     args = parser.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import paper_figures as PF
     selected = [fn for fn in PF.ALL
                 if not args.only or args.only in fn.__name__]

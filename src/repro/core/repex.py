@@ -428,7 +428,6 @@ class REMDDriver:
         failure masks, see ``patterns.fused_cycle``) compile into the
         scan body.
         """
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.sharding import ensemble_specs
@@ -447,13 +446,13 @@ class REMDDriver:
         chunk = self._chunk_scan(chunk_cycles, axis_name="replica",
                                  n_shards=n_shards)
         espec = ensemble_specs(ens)
-        # check_rep=False: the replicated outputs (assignment, stats, ...)
+        # check_vma=False: the replicated outputs (assignment, stats, ...)
         # come out of all_gather-fed replicated math, which shard_map's
-        # static replication checker cannot infer through lax.scan
-        body = shard_map(chunk, mesh,
-                         in_specs=(espec, espec.state, P()),
-                         out_specs=(espec, espec.state, P(), P()),
-                         check_rep=False)
+        # static varying-axes checker cannot infer through lax.scan
+        body = jax.shard_map(chunk, mesh=mesh,
+                             in_specs=(espec, espec.state, P()),
+                             out_specs=(espec, espec.state, P(), P()),
+                             check_vma=False)
         jitted = jax.jit(body)
         if wire:
             # wire ledger: AOT-compile the chunk (lower -> compile) so

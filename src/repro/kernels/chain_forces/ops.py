@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import (default_interpret, default_use_kernel,
-                           pack_coords, pad_to_block)
+                           pad_to_block)
 from repro.kernels.chain_forces import kernel as K
 from repro.kernels.chain_forces import ref
 
@@ -39,14 +39,13 @@ class ChainForcePack(NamedTuple):
     """Kernel-ready bonded topology (static ints + device arrays)."""
     n_atoms: int
     n_pad: int
-    bp: int                   # lane-padded bond slot width
-    ap: int                   # lane-padded angle slot width
-    qp: int                   # lane-padded quad slot width
-    gmat: jax.Array           # (Np, Tp) one-hot gather/scatter matrix
-    bond_par: jax.Array       # (8, bp): rows 0 = r0, 1 = k
-    ang_par: jax.Array        # (8, ap): rows 0 = t0, 1 = k
-    quad_par: jax.Array       # (8, qp): rows 0 = n, 1 = k, 2 = phase,
-                              #          3 = is_phi, 4 = is_psi
+    tb: int                   # edges per term block (one lane tile)
+    n_blocks: int             # K term blocks
+    gmat: jax.Array           # (Np, K * 9 * tb) bf16 one-hot gather/scatter
+    bond_par: jax.Array       # (8, K * tb): rows 0 = r0, 1 = k
+    ang_par: jax.Array        # (8, K * tb): rows 0 = t0, 1 = k
+    quad_par: jax.Array       # (8, K * tb): rows 0 = n, 1 = k, 2 = phase,
+                              #              3 = is_phi, 4 = is_psi
     top: ref.ChainTopology    # plain-array topology for the jnp path
     slots: ref.BondedSlots    # (N, S) inverted incidence for sparse path
 
@@ -55,29 +54,29 @@ def build_pack(system, lane: int = 128) -> ChainForcePack:
     """Pack a system's bonded topology for the kernel (host-side, once).
 
     ``system`` is duck-typed (any object with MolecularSystem's bonded
-    attributes).  Padded slots gather atom 0 columns of ZEROS (the
-    one-hot matrix simply has no entry) and carry k = 0 parameters, so
-    they contribute exactly nothing.
+    attributes).  Edge ``e`` of every term class sits in term block
+    ``e // lane`` at lane ``e % lane``; within a block the nine roles
+    follow each other (``K.N_ROLES``).  Padded slots gather atom columns
+    of ZEROS (the one-hot matrix simply has no entry) and carry k = 0
+    parameters, so they contribute exactly nothing.
     """
     top = ref.chain_topology(system)
     bonds = np.asarray(top.bonds)
     angles = np.asarray(top.angles)
     quads = np.asarray(top.quads)
     nb, na, nq = len(bonds), len(angles), len(quads)
-    bp, ap, qp = (pad_to_block(nb, lane), pad_to_block(na, lane),
-                  pad_to_block(nq, lane))
+    n_blocks = -(-max(nb, na, nq) // lane)
+    width = n_blocks * lane
     n_pad = pad_to_block(int(system.n_atoms), lane)
 
-    gmat = np.zeros((n_pad, 2 * bp + 3 * ap + 4 * qp), np.float32)
-    offs, roles = 0, []
-    for width, cols in ((bp, bonds.T), (ap, angles.T), (qp, quads.T)):
-        for role in cols:
-            roles.append((offs, role))
-            offs += width
-    for off, role in roles:
-        gmat[role, off + np.arange(len(role))] = 1.0
+    gmat = np.zeros((n_pad, n_blocks * K.N_ROLES * lane), np.float32)
+    roles = list(bonds.T) + list(angles.T) + list(quads.T)
+    for rho, atoms in enumerate(roles):
+        e = np.arange(len(atoms))
+        gmat[atoms, (e // lane) * K.N_ROLES * lane + rho * lane
+             + e % lane] = 1.0
 
-    def par(width, rows):
+    def par(rows):
         out = np.zeros((8, width), np.float32)
         for i, row in enumerate(rows):
             out[i, : len(row)] = np.asarray(row)
@@ -88,12 +87,12 @@ def build_pack(system, lane: int = 128) -> ChainForcePack:
     is_phi[nq - 2] = 1.0
     is_psi[nq - 1] = 1.0
     return ChainForcePack(
-        n_atoms=int(system.n_atoms), n_pad=n_pad, bp=bp, ap=ap, qp=qp,
-        gmat=jnp.asarray(gmat),
-        bond_par=jnp.asarray(par(bp, (top.bond_r0, top.bond_k))),
-        ang_par=jnp.asarray(par(ap, (top.angle_t0, top.angle_k))),
-        quad_par=jnp.asarray(par(qp, (top.quad_n, top.quad_k,
-                                      top.quad_phase, is_phi, is_psi))),
+        n_atoms=int(system.n_atoms), n_pad=n_pad, tb=lane,
+        n_blocks=n_blocks, gmat=jnp.asarray(gmat, jnp.bfloat16),
+        bond_par=jnp.asarray(par((top.bond_r0, top.bond_k))),
+        ang_par=jnp.asarray(par((top.angle_t0, top.angle_k))),
+        quad_par=jnp.asarray(par((top.quad_n, top.quad_k, top.quad_phase,
+                                  is_phi, is_psi))),
         top=top,
         slots=ref.bonded_slots(top),
     )
@@ -129,11 +128,18 @@ def bonded_forces(pos, pack: ChainForcePack,
                                             umbrella_center, umbrella_k)
         return ref.bonded_forces(pos, pack.top, umbrella_center, umbrella_k)
     interp = default_interpret() if interpret is None else interpret
-    coords = pack_coords(pos, pack.n_pad)
-    bias_par = _pack_bias(umbrella_center, umbrella_k, pos.shape[0])
+    r, n = pos.shape[0], pack.n_atoms
+    rb = K.replica_block(r)
+    # (R, N, 3) -> component-major replica blocks (R/RB, rows, Np)
+    blk = jnp.transpose(pos.astype(jnp.float32).reshape(r // rb, rb, n, 3),
+                        (0, 3, 1, 2)).reshape(r // rb, 3 * rb, n)
+    coords = jnp.zeros((r // rb, K.row_pad(rb), pack.n_pad), jnp.float32)
+    coords = coords.at[:, :3 * rb, :n].set(blk)
+    bias_par = _pack_bias(umbrella_center, umbrella_k, r)
     out, e = K.chain_forces_kernel_batched(
         coords, pack.gmat, pack.bond_par, pack.ang_par, pack.quad_par,
-        bias_par, bp=pack.bp, ap=pack.ap, qp=pack.qp,
+        bias_par.reshape(r // rb, rb, 8), tb=pack.tb,
         bias=umbrella_center is not None, interpret=interp)
-    forces = jnp.swapaxes(out[:, 0:3, : pack.n_atoms], 1, 2)
-    return forces.astype(pos.dtype), e[:, 0]
+    forces = jnp.transpose(out[:, :n, :3 * rb].reshape(r // rb, n, 3, rb),
+                           (0, 3, 1, 2)).reshape(r, n, 3)
+    return forces.astype(pos.dtype), e.reshape(r)

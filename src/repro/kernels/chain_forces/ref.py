@@ -251,7 +251,8 @@ def bonded_forces(pos, top: ChainTopology,
     edges, e = _edge_grads(pos, top, umbrella_center, umbrella_k)
     out = jax.lax.dot_general(
         edges, top.inc_stack,
-        (((edges.ndim - 1,), (1,)), ((edges.ndim - 3,), (0,))))
+        (((edges.ndim - 1,), (1,)), ((edges.ndim - 3,), (0,))),
+        precision=jax.lax.Precision.HIGHEST)
     force = -jnp.swapaxes(jnp.sum(out, axis=0), -1, -2)    # (..., N, 3)
     return force, e
 

@@ -3,11 +3,11 @@
 Packs the replica stack ONCE (coordinates + per-atom LJ/charge rows,
 velocities, masses, exclusion mask, topology pack), then runs
 ``max_steps + 1`` fused kernel launches inside one ``fori_loop`` —
-per-iteration work is exactly: draw the noise block (unrolled threefry,
-``md.noise``), build the (R, 8) step-scalar rows, launch.  Unpacking
-happens once at the end; positions never leave the packed layout
-between iterations, which is the point — the per-pass path pays
-pack/unpack + two kernel dispatches per force evaluation.
+per-iteration work is exactly: draw the noise block
+(``integrators.step_noise``), build the (R, 1, 8) step-scalar rows,
+launch.  Unpacking happens once at the end; positions never leave the
+packed layout between iterations, which is the point — the per-pass
+path pays pack/unpack + two kernel dispatches per force evaluation.
 
 Same iteration count, noise stream and masking as
 ``integrators.propagate_replica_major_fused`` (the jnp fused body);
@@ -28,7 +28,6 @@ from repro.kernels.fused_propagate import kernel as K
 from repro.kernels.lj_forces import ops as nb_ops
 from repro.kernels.lj_forces import ref as nb_ref
 from repro.md import integrators as I
-from repro.md import noise as NZ
 
 
 def kernel_supported(nonbonded: str) -> bool:
@@ -61,7 +60,7 @@ def fused_propagate(state, pack, system, ctrl, n_steps, rngs,
         system.nb_mask)
     u_c = ctrl.get("umbrella_center")
     u_k = ctrl.get("umbrella_k")
-    bias_par = chain_ops._pack_bias(u_c, u_k, r)
+    bias_par = chain_ops._pack_bias(u_c, u_k, r)[:, None]
     salt = ctrl.get("salt")
     salt_col = (jnp.ones((r,), jnp.float32) if salt is None
                 else (1.0 - 0.5 * salt).astype(jnp.float32))
@@ -70,22 +69,22 @@ def fused_propagate(state, pack, system, ctrl, n_steps, rngs,
     _, noise_scale = I.baoab_scales(system.masses, ctrl["temperature"],
                                     dt, gamma)
     launch = functools.partial(
-        K.fused_baoab_kernel_batched, bp=pack.bp, ap=pack.ap, qp=pack.qp,
-        bias=u_c is not None, coulomb=nb_ref.COULOMB,
-        c1=float(jnp.exp(jnp.float32(-gamma * dt))),
+        K.fused_baoab_kernel_batched, tb=pack.tb, bias=u_c is not None,
+        coulomb=nb_ref.COULOMB, c1=float(jnp.exp(jnp.float32(-gamma * dt))),
         half_kick=0.5 * dt * I.AKMA, half_dt=0.5 * dt, interpret=interp)
 
     def body(i, carry):
         cc, vv = carry
-        noise_i = NZ.step_noise_unrolled(rngs, i, (n, 3))
+        noise_i = I.step_noise(rngs, i, (n, 3))
         nz = pack_coords(noise_scale * noise_i, n_pad)
         trail = ((i >= 1) & (i <= n_steps)).astype(jnp.float32)
         lead = ((i < n_steps) & (i < max_steps)).astype(jnp.float32)
         st = (jnp.zeros((r, 8), jnp.float32)
               .at[:, 0].set(trail).at[:, 1].set(lead)
               .at[:, 2].set(salt_col))
-        return launch(cc, vv, nz, st, bias_par, pack.gmat, pack.bond_par,
-                      pack.ang_par, pack.quad_par, mask, mass_rows)
+        return launch(cc, vv, nz, st[:, None], bias_par, pack.gmat,
+                      pack.bond_par, pack.ang_par, pack.quad_par, mask,
+                      mass_rows)
 
     cc, vv = jax.lax.fori_loop(0, max_steps + 1, body, (c, v))
     return {"pos": jnp.swapaxes(cc[:, 0:3, :n], 1, 2).astype(pos.dtype),

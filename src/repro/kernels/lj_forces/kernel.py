@@ -28,6 +28,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.chain_forces.kernel import (_DN, VMEM_CAP_BYTES,
+                                               one_hot_dot)
+
 
 def _pair_blocks(ci, cj, sigma, box, bi, bj, ii, jj):
     """Returns (r2, s6, mask, disp) for one (BI, BJ) tile."""
@@ -194,12 +197,18 @@ def _nonbonded_kernel_batched(ci_ref, cj_ref, m_ref, f_ref, elj_ref,
 
     rows, e_lj, e_el = nonbonded_pair_rows(ci_ref[0], cj_ref[0], m_ref[...],
                                            coulomb=coulomb)
-    elj_ref[0, 0] += e_lj
-    eel_ref[0, 0] += e_el
+    elj_ref[...] += jnp.reshape(e_lj, (1, 1, 1))
+    eel_ref[...] += jnp.reshape(e_el, (1, 1, 1))
     f_ref[...] += rows[None]
 
 
-_DN = (((1,), (0,)), ((), ()))     # contract last dim of lhs w/ first of rhs
+# Largest system the sparse kernel holds in VMEM: each program builds
+# (Np, Np) iota and one-hot planes for its neighbor-slot gathers, and
+# its VMEM need grows with the neighbor capacity too.  At this size it
+# compiles for v5e at any capacity up to N; larger systems compile
+# only with few slots (6,144 atoms at 128, not at 512).  Pinned by
+# tests/test_tpu_compile.py.
+SPARSE_MAX_ATOMS = 1024
 
 
 def _nonbonded_sparse_kernel_batched(c_ref, idx_ref, val_ref, f_ref,
@@ -224,9 +233,8 @@ def _nonbonded_sparse_kernel_batched(c_ref, idx_ref, val_ref, f_ref,
         facc, elj, eel = carry
         idx_row = idx_ref[0, pl.ds(k, 1), :]       # (1, Np)
         val_row = val_ref[0, pl.ds(k, 1), :]
-        oh = (iota == idx_row).astype(jnp.float32)
-        g = jax.lax.dot_general(c, oh, _DN,
-                                preferred_element_type=jnp.float32)
+        oh = (iota == idx_row).astype(jnp.bfloat16)
+        g = one_hot_dot(c, oh, _DN)
         dx, dy, dz = xi - g[0:1], yi - g[1:2], zi - g[2:3]
         r2 = dx * dx + dy * dy + dz * dz
         mask = val_row * (r2 <= cutoff * cutoff).astype(jnp.float32)
@@ -250,8 +258,8 @@ def _nonbonded_sparse_kernel_batched(c_ref, idx_ref, val_ref, f_ref,
     facc, elj, eel = jax.lax.fori_loop(
         0, k_pad, body, (facc, jnp.zeros(()), jnp.zeros(())))
     f_ref[...] = facc[None]
-    elj_ref[0, 0] = elj
-    eel_ref[0, 0] = eel
+    elj_ref[...] = jnp.reshape(elj, (1, 1, 1))
+    eel_ref[...] = jnp.reshape(eel, (1, 1, 1))
 
 
 def nonbonded_sparse_kernel_batched(coords, idx, valid, *, coulomb: float,
@@ -260,7 +268,7 @@ def nonbonded_sparse_kernel_batched(coords, idx, valid, *, coulomb: float,
     """coords (R, 8, Np) packed (rows as ``nonbonded_kernel_batched``);
     idx/valid (R, Kp, Np) SLOT-MAJOR transposed neighbor tables.
     Returns (forces (R, 8, Np): rows 0..2 = LJ, 3..5 = elec;
-    e_lj (R, 1); e_el (R, 1)) from one launch."""
+    e_lj (R, 1, 1); e_el (R, 1, 1)) from one launch."""
     r, _, n_pad = coords.shape
     k_pad = idx.shape[1]
     kern = functools.partial(_nonbonded_sparse_kernel_batched,
@@ -272,11 +280,13 @@ def nonbonded_sparse_kernel_batched(coords, idx, valid, *, coulomb: float,
                   pl.BlockSpec((1, k_pad, n_pad), lambda q: (q, 0, 0)),
                   pl.BlockSpec((1, k_pad, n_pad), lambda q: (q, 0, 0))],
         out_specs=[pl.BlockSpec((1, 8, n_pad), lambda q: (q, 0, 0)),
-                   pl.BlockSpec((1, 1), lambda q: (q, 0)),
-                   pl.BlockSpec((1, 1), lambda q: (q, 0))],
+                   pl.BlockSpec((1, 1, 1), lambda q: (q, 0, 0)),
+                   pl.BlockSpec((1, 1, 1), lambda q: (q, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((r, 8, n_pad), jnp.float32),
-                   jax.ShapeDtypeStruct((r, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((r, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((r, 1, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((r, 1, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_CAP_BYTES),
         interpret=interpret,
     )(coords, idx, valid)
 
@@ -286,7 +296,7 @@ def nonbonded_kernel_batched(coords, nb_mask, *, coulomb: float,
     """coords (R, 8, N) packed (rows 0..2 xyz, 3 validity, 4 sigma,
     5 sqrt(eps), 6 charge); nb_mask (N, N).  Returns
     (forces (R, 8, N): rows 0..2 = LJ, 3..5 = elec;
-     e_lj (R, 1); e_el (R, 1)) from one launch."""
+     e_lj (R, 1, 1); e_el (R, 1, 1)) from one launch."""
     r, _, n = coords.shape
     block = min(block, n)
     assert n % block == 0
@@ -299,10 +309,10 @@ def nonbonded_kernel_batched(coords, nb_mask, *, coulomb: float,
                   pl.BlockSpec((1, 8, block), lambda q, i, j: (q, 0, j)),
                   pl.BlockSpec((block, block), lambda q, i, j: (i, j))],
         out_specs=[pl.BlockSpec((1, 8, block), lambda q, i, j: (q, 0, i)),
-                   pl.BlockSpec((1, 1), lambda q, i, j: (q, 0)),
-                   pl.BlockSpec((1, 1), lambda q, i, j: (q, 0))],
+                   pl.BlockSpec((1, 1, 1), lambda q, i, j: (q, 0, 0)),
+                   pl.BlockSpec((1, 1, 1), lambda q, i, j: (q, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((r, 8, n), jnp.float32),
-                   jax.ShapeDtypeStruct((r, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((r, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((r, 1, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((r, 1, 1), jnp.float32)],
         interpret=interpret,
     )(coords, coords, nb_mask)

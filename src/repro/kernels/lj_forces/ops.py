@@ -129,7 +129,7 @@ def nonbonded_batched(pos, lj_sigma, lj_eps, charges, nb_mask,
         c, mask, coulomb=ref.COULOMB, block=block, interpret=interp)
     f_lj = jnp.swapaxes(out[:, 0:3, :n], 1, 2).astype(pos.dtype)
     f_el = jnp.swapaxes(out[:, 3:6, :n], 1, 2).astype(pos.dtype)
-    return f_lj, f_el, e_lj[:, 0], e_el[:, 0]
+    return f_lj, f_el, e_lj[:, 0, 0], e_el[:, 0, 0]
 
 
 def nonbonded(pos, lj_sigma, lj_eps, charges, nb_mask,
@@ -176,7 +176,7 @@ def nonbonded_sparse_batched(pos, lj_sigma, lj_eps, charges, idx, valid,
         interpret=interp)
     f_lj = jnp.swapaxes(out[:, 0:3, :n], 1, 2).astype(pos.dtype)
     f_el = jnp.swapaxes(out[:, 3:6, :n], 1, 2).astype(pos.dtype)
-    return f_lj, f_el, e_lj[:, 0], e_el[:, 0]
+    return f_lj, f_el, e_lj[:, 0, 0], e_el[:, 0, 0]
 
 
 def nonbonded_sparse(pos, lj_sigma, lj_eps, charges, idx, valid,

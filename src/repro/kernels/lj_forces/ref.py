@@ -51,7 +51,8 @@ def _coef_force(coef, pos):
     one (..., N, N) x (..., N, 3) batched GEMM + elementwise — the
     identity that keeps the pairwise force a rank-3 computation."""
     return (jnp.sum(coef, axis=-1)[..., None] * pos
-            - jnp.einsum("...ij,...jc->...ic", coef, pos))
+            - jnp.einsum("...ij,...jc->...ic", coef, pos,
+                         precision=jax.lax.Precision.HIGHEST))
 
 
 def _nonbonded_coefs(pos, lj_sigma, lj_eps, charges, nb_mask,

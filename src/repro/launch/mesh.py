@@ -43,7 +43,11 @@ def make_replica_mesh(n_shards: int = 0):
             f"only {jax.device_count()} are visible (on CPU, set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=N before "
             f"jax initializes)")
-    return jax.make_mesh((n_shards,), ("replica",))
+    # Auto axis: arrays on this mesh carry no sharding in their types, so
+    # jitted code outside the shard_map (the telemetry phase probes,
+    # the chunk-boundary bookkeeping) partitions them by propagation
+    return jax.make_mesh((n_shards,), ("replica",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def best_replica_shards(n_replicas: int,

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.config import RepExConfig
 from repro.core import REMDDriver, control_multiset_ok
+from repro.launch.cache import enable_compile_cache
 from repro.md import LJEngine, MDEngine
 from repro.md.system import chain_molecule
 
@@ -75,6 +76,7 @@ def main():
                     help="sample phase timings every Nth chunk boundary "
                          "(0 = off; only with --report-out)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = RepExConfig(
         engine=args.engine,

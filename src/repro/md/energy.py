@@ -42,6 +42,10 @@ from repro.kernels import wrap_deg as _wrap_deg
 from repro.kernels.lj_forces.ref import COULOMB  # noqa: F401 — canonical
 from repro.md.system import MolecularSystem
 
+# every f32 dot runs at full precision: a TPU default pass rounds its
+# operands to bf16 (~16 A at x = 4000 A)
+_HI = jax.lax.Precision.HIGHEST
+
 
 def _dihedral_angle(pos, quad) -> jax.Array:
     """Signed dihedral (radians) for one quad of atom indices."""
@@ -50,8 +54,8 @@ def _dihedral_angle(pos, quad) -> jax.Array:
     n1 = jnp.cross(b0, b1)
     n2 = jnp.cross(b1, b2)
     m1 = jnp.cross(n1, b1 / (jnp.linalg.norm(b1) + 1e-9))
-    x = jnp.dot(n1, n2)
-    y = jnp.dot(m1, n2)
+    x = jnp.dot(n1, n2, precision=_HI)
+    y = jnp.dot(m1, n2, precision=_HI)
     return jnp.arctan2(y, x)
 
 
@@ -288,7 +292,8 @@ def _pair_energies_bwd(res, g):
             + g_el[:, None, None] * COULOMB * qq
             / (r2 * jnp.sqrt(r2))) * nb_mask
     d_pos = -(jnp.sum(coef, axis=-1)[..., None] * pos
-              - jnp.einsum("...ij,...jc->...ic", coef, pos))
+              - jnp.einsum("...ij,...jc->...ic", coef, pos,
+                           precision=_HI))
     zeros = jax.tree.map(jnp.zeros_like, (lj_sigma, lj_eps, charges,
                                           nb_mask))
     return (d_pos,) + zeros
@@ -334,7 +339,8 @@ def batched_features(pos, sys: MolecularSystem) -> Dict[str, jax.Array]:
 
 def sparse_pair_energies(pos, sys: MolecularSystem, idx, valid,
                          cutoff: float, use_kernel: bool = False,
-                         pair=None) -> Tuple[jax.Array, jax.Array]:
+                         pair=None, interpret=None
+                         ) -> Tuple[jax.Array, jax.Array]:
     """(LJ, elec) energies from the O(N * K) neighbor-list sweep.
 
     The sparse analogue of :func:`_batched_pair_terms` — the TRUNCATED
@@ -346,19 +352,20 @@ def sparse_pair_energies(pos, sys: MolecularSystem, idx, valid,
     from repro.kernels.lj_forces import ops as nb_ops
     _, _, e_lj, e_el = nb_ops.nonbonded_sparse(
         pos, sys.lj_sigma, sys.lj_eps, sys.charges, idx, valid, cutoff,
-        use_kernel=use_kernel, pair=pair)
+        use_kernel=use_kernel, pair=pair, interpret=interpret)
     return e_lj, e_el
 
 
 def sparse_features(pos, sys: MolecularSystem, idx, valid, cutoff: float,
-                    use_kernel: bool = False, pair=None
+                    use_kernel: bool = False, pair=None, interpret=None
                     ) -> Dict[str, jax.Array]:
     """Per-replica features under the neighbor-list truncated potential:
     same keys/shapes as :func:`batched_features`, with the pairwise sums
     evaluated on the (R, N, K) list instead of all (R, N, N) pairs."""
     e_bonded, phi, psi = _batched_bonded_terms(pos, sys)
     e_lj, e_elec = sparse_pair_energies(pos, sys, idx, valid, cutoff,
-                                        use_kernel=use_kernel, pair=pair)
+                                        use_kernel=use_kernel, pair=pair,
+                                        interpret=interpret)
     return {
         "u_base": e_bonded + e_lj,
         "u_elec": e_elec,

@@ -60,8 +60,11 @@ from jax import lax
 
 import numpy as np
 
-from repro.kernels import default_use_kernel
+from repro.kernels import default_interpret, default_use_kernel
+from repro.kernels.chain_forces import kernel as chain_kernel
 from repro.kernels.chain_forces import ops as chain_ops
+from repro.kernels.fused_propagate import kernel as fused_kernel
+from repro.kernels.lj_forces import kernel as nb_kernel
 from repro.kernels.lj_forces import ops as nb_ops
 from repro.md import energy as E
 from repro.md import integrators as I
@@ -232,6 +235,8 @@ class MDEngine:
             + (("bond",) if self.max_bond_stretch is not None else ()))
         self._use_kernel = (default_use_kernel() if use_force_kernels is None
                             else use_force_kernels)
+        self._interpret = default_interpret()
+        self._check_kernel_limits()
         self._pack = (chain_ops.build_pack(self.system)
                       if force_path in ("pallas", "fused") else None)
         if nonbonded == "sparse":
@@ -272,6 +277,38 @@ class MDEngine:
                 raise ValueError(f"nlist_build must be 'dense' or 'cell', "
                                  f"got {nlist_build!r}")
             self.nlist_build = nlist_build
+
+    @property
+    def force_kernels(self) -> str:
+        """How this engine's force passes run, decided once at
+        construction from the backend: ``"compiled"`` (Pallas kernels
+        compiled for the chip), ``"interpret"`` (the Pallas interpreter,
+        a CPU correctness harness) or ``"jnp"`` (the jnp reference
+        passes).  A chip run asserts ``"compiled"``."""
+        if self.force_path not in ("pallas", "fused") or not self._use_kernel:
+            return "jnp"
+        return "interpret" if self._interpret else "compiled"
+
+    def _check_kernel_limits(self):
+        """Refuse, at construction, a system larger than the compiled
+        kernels this engine would launch hold in VMEM (limits from the
+        v5e compile tests, tests/test_tpu_compile.py).  A compiled path
+        never falls back to the jnp passes on its own."""
+        if self.force_kernels != "compiled":
+            return
+        n = int(self.system.n_atoms)
+        limits = [("chain_forces", chain_kernel.MAX_ATOMS)]
+        if self.nonbonded == "sparse":
+            limits.append(("sparse nonbonded", nb_kernel.SPARSE_MAX_ATOMS))
+        elif self.force_path == "fused":
+            limits.append(("fused_propagate", fused_kernel.MAX_ATOMS))
+        for name, limit in limits:
+            if n > limit:
+                raise ValueError(
+                    f"force_path={self.force_path!r} with "
+                    f"nonbonded={self.nonbonded!r} runs the {name} Pallas "
+                    f"kernel, which holds at most {limit} atoms in TPU "
+                    f"VMEM; this system has {n}")
 
     # -- neighbor-list plumbing (nonbonded="sparse") -----------------------
 
@@ -366,11 +403,13 @@ class MDEngine:
             nlist = self._refresh_nlist(pos, nlist)
             f, _ = chain_ops.bonded_forces(pos, self._pack, u_c, u_k,
                                            use_kernel=self._use_kernel,
+                                           interpret=self._interpret,
                                            sparse=self.bonded == "sparse")
             f = f + nb_ops.nonbonded_force_sparse(
                 pos, sys.lj_sigma, sys.lj_eps, sys.charges,
                 nlist["idx"], nlist["valid"], self.cutoff, salt_scale,
-                use_kernel=self._use_kernel, pair=nlist.get("pair"))
+                use_kernel=self._use_kernel, interpret=self._interpret,
+                pair=nlist.get("pair"))
             return f, nlist
 
         return force_aux
@@ -397,7 +436,7 @@ class MDEngine:
         Pallas launch (``kernels.fused_propagate``).  Off-TPU, and for
         ``nonbonded="sparse"`` (whose neighbor-list aux carry and
         ``nb_pair_planes`` ride the loop), the jitted fused jnp body
-        runs — hoisted scales, in-loop unrolled-threefry noise, the
+        runs — hoisted scales, in-loop jax.random noise, the
         shared ``baoab_fused_iteration`` update.  Both keep every force
         evaluation inside the loop body, so the bitwise-across-chunk-
         sizes guarantee carries over unchanged."""
@@ -414,7 +453,7 @@ class MDEngine:
             from repro.kernels.fused_propagate import ops as fused_ops
             return fused_ops.fused_propagate(
                 state, self._pack, sys, ctrl, n_steps, rngs, max_steps,
-                self.dt, self.gamma)
+                self.dt, self.gamma, interpret=self._interpret)
         force_fn = self._analytic_force_fn(ctrl)
         out, _ = I.propagate_replica_major_fused(
             {"pos": state["pos"], "vel": state["vel"]},
@@ -438,10 +477,12 @@ class MDEngine:
         def force_fn(pos):
             f, _ = chain_ops.bonded_forces(pos, self._pack, u_c, u_k,
                                            use_kernel=self._use_kernel,
+                                           interpret=self._interpret,
                                            sparse=self.bonded == "sparse")
             return f + nb_ops.nonbonded_force(
                 pos, sys.lj_sigma, sys.lj_eps, sys.charges, sys.nb_mask,
-                salt_scale, use_kernel=self._use_kernel)
+                salt_scale, use_kernel=self._use_kernel,
+                interpret=self._interpret)
 
         return force_fn
 
@@ -494,7 +535,8 @@ class MDEngine:
             return E.sparse_features(state["pos"], self.system,
                                      nl["idx"], nl["valid"], self.cutoff,
                                      use_kernel=self._use_kernel,
-                                     pair=nl.get("pair"))
+                                     pair=nl.get("pair"),
+                                     interpret=self._interpret)
         if self.batched:
             return E.batched_features(state["pos"], self.system)
         sys = self.system

@@ -184,11 +184,9 @@ def propagate_replica_major_fused(state, force_aux_fn, aux, masses,
 
       * the loop-invariant O-step scales are hoisted
         (:func:`baoab_scales` — value-identical to the in-body form);
-      * the noise block is drawn INSIDE the body through the unrolled
-        threefry (``noise.step_noise_unrolled``) — bitwise the same
-        ``fold_in(key_r, t)`` stream, but ~1 fused op instead of the
-        pre-drawn stack's two rolled hash loops + per-iteration gather,
-        and O(R * N) live memory instead of O(S * R * N);
+      * the noise block is drawn INSIDE the body (:func:`step_noise`,
+        the same ``fold_in(key_r, t)`` stream as the pre-drawn stack),
+        so live memory is O(R * N) instead of O(S * R * N);
       * force eval + update share one body via
         :func:`baoab_fused_iteration`.
 
@@ -197,14 +195,13 @@ def propagate_replica_major_fused(state, force_aux_fn, aux, masses,
     driver's bitwise-across-chunk-sizes guarantee carries over
     unchanged.  Returns ({"pos", "vel"}, aux).
     """
-    from repro.md import noise as NZ
     c1, noise_scale = baoab_scales(masses, temperature, dt, gamma)
     shape = state["pos"].shape[1:]
 
     def body(i, carry):
         pos, vel, aux = carry
         f, aux = force_aux_fn(pos, aux)
-        noise_i = NZ.step_noise_unrolled(rngs, i, shape)
+        noise_i = step_noise(rngs, i, shape)
         pos, vel = baoab_fused_iteration(i, pos, vel, f, noise_i, c1,
                                          noise_scale, masses, n_steps,
                                          max_steps, dt, box)
@@ -213,6 +210,15 @@ def propagate_replica_major_fused(state, force_aux_fn, aux, masses,
     pos, vel, aux = jax.lax.fori_loop(
         0, max_steps + 1, body, (state["pos"], state["vel"], aux))
     return {"pos": pos, "vel": vel}, aux
+
+
+def step_noise(rngs, t, shape) -> jax.Array:
+    """One iteration's noise block (R, *shape): replica r draws
+    ``normal(fold_in(key_r, t), shape)`` — the stream every propagate
+    path consumes, so exchange decisions agree bit for bit across
+    paths.  ``t`` may be traced (a loop index)."""
+    return jax.vmap(lambda k: jax.random.normal(jax.random.fold_in(k, t),
+                                                shape))(rngs)
 
 
 def stacked_step_noise(rngs, max_steps: int, shape) -> jax.Array:
@@ -225,10 +231,8 @@ def stacked_step_noise(rngs, max_steps: int, shape) -> jax.Array:
     whose whole premise is short cycles (``md_steps_per_cycle`` tens to
     hundreds), but worth revisiting if propagate is ever driven with
     very large ``max_steps`` on large systems."""
-    ts = jnp.arange(max_steps)
-    return jax.vmap(lambda t: jax.vmap(
-        lambda k: jax.random.normal(jax.random.fold_in(k, t), shape))(
-        rngs))(ts)
+    return jax.vmap(lambda t: step_noise(rngs, t, shape))(
+        jnp.arange(max_steps))
 
 
 def kinetic_temperature(vel, masses):

@@ -27,8 +27,8 @@ oracle stays one dense/dense run.
 
 The second half of the file holds the seeded property pins (the
 container has no ``hypothesis``; randomization is explicit via
-parametrized seeds): single-iteration bitwise delegation, the unrolled
-threefry noise stream, OU stationary statistics of the fused loop, and
+parametrized seeds): single-iteration bitwise delegation, the in-body
+noise stream, OU stationary statistics of the fused loop, and
 100-step stability on randomized chain topologies — plus the
 feature-interaction pins (kill/resume with the fused+sparse+planes+
 relaunch-budget stack live; telemetry observer-effect on the fused
@@ -45,7 +45,6 @@ from repro.core import (REMDDriver, build_grid, control_multiset_ok,
 from repro.launch.mesh import make_replica_mesh
 from repro.md import MDEngine
 from repro.md import integrators as I
-from repro.md import noise as NZ
 from repro.md.system import chain_molecule
 from repro.obs import Telemetry
 
@@ -195,16 +194,21 @@ def test_matrix_sharded_cell_8shards():
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
 @pytest.mark.parametrize("n", [5, 8])
-def test_property_unrolled_noise_stream_bitwise(seed, n):
-    """The fused path's in-loop unrolled-threefry draw is BITWISE the
-    pre-drawn stacked stream, per step, for odd (padded lane) and even
-    draw sizes — the hinge of cross-path decision equality."""
+def test_property_in_body_noise_stream_bitwise(seed, n):
+    """The fused path's in-loop draw (a traced loop index) is BITWISE
+    the pre-drawn stacked stream, per step, for odd and even draw sizes
+    — the hinge of cross-path decision equality."""
     rngs = jax.random.split(jax.random.key(seed), 4)
     stacked = I.stacked_step_noise(rngs, 6, (n, 3))
+
+    def in_loop(rngs):
+        return jax.lax.fori_loop(
+            0, 6, lambda t, acc: acc.at[t].set(I.step_noise(rngs, t, (n, 3))),
+            jnp.zeros_like(stacked))
+
+    got = jax.jit(in_loop)(rngs)
     for t in range(6):
-        got = jax.jit(NZ.step_noise_unrolled,
-                      static_argnums=(2,))(rngs, jnp.asarray(t), (n, 3))
-        np.testing.assert_array_equal(np.asarray(got),
+        np.testing.assert_array_equal(np.asarray(got[t]),
                                       np.asarray(stacked[t]), err_msg=f"t={t}")
 
 

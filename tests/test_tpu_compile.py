@@ -1,0 +1,148 @@
+"""Compile the MD kernels for a described TPU v5e, without the chip.
+
+Interpret mode runs a Pallas kernel's body on the CPU but never asks
+Mosaic, the TPU kernel compiler, to lower it: block shapes that break
+the (8, 128) tiling, primitives Mosaic has no lowering for, and blocks
+that overflow VMEM all pass there.  These tests compile each kernel of
+the MD engine for a v5e chip that JAX describes but does not attach, at
+the sizes the engine runs: the main path (``chain_forces`` and the
+dense nonbonded kernel) at the paper's 2,881 atoms and R = 64, the
+fused and sparse kernels at the largest system the engine admits on a
+TPU, and ``exchange_matrix`` at the paper's 1,728 replicas.
+
+The topology is described inside a module fixture (never at import):
+only one process at a time may load the TPU compiler library.
+"""
+import types
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import pad_to_block
+from repro.kernels.chain_forces import kernel as CK
+from repro.kernels.exchange_matrix import kernel as XK
+from repro.kernels.fused_propagate import kernel as FK
+from repro.kernels.lj_forces import kernel as LK
+from repro.kernels.lj_forces.ref import COULOMB
+
+N_PAPER = 2881
+R_MAIN = 64
+LANE = 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip is written to the persistent
+        # cache but cannot be read back without the chip
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _compile(fn, shardings, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=shardings)
+            for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _chain_shapes(n_atoms, n_replicas):
+    """The kernel's operand shapes for ``chain_molecule(n_atoms)``:
+    n - 1 bonds, n - 2 angles, n - 3 torsions + the two feature quads."""
+    n_pad = pad_to_block(n_atoms, LANE)
+    n_k = -(-(n_atoms - 1) // LANE)
+    rb = CK.replica_block(n_replicas)
+    f32 = jnp.float32
+    return [((n_replicas // rb, CK.row_pad(rb), n_pad), f32),
+            ((n_pad, n_k * CK.N_ROLES * LANE), jnp.bfloat16),
+            ((8, n_k * LANE), f32), ((8, n_k * LANE), f32),
+            ((8, n_k * LANE), f32), ((n_replicas // rb, rb, 8), f32)]
+
+
+@pytest.mark.parametrize("bias", [False, True])
+def test_chain_forces_compiles_v5e(one_chip, bias):
+    """The main path's bonded kernel at the paper's 2,881 atoms."""
+    _compile(lambda *a: CK.chain_forces_kernel_batched(
+        *a, tb=LANE, bias=bias, interpret=False),
+        one_chip, *_chain_shapes(N_PAPER, R_MAIN))
+
+
+def test_chain_forces_compiles_v5e_at_limit(one_chip):
+    """The largest system the engine admits on the bonded kernel."""
+    _compile(lambda *a: CK.chain_forces_kernel_batched(
+        *a, tb=LANE, bias=True, interpret=False),
+        one_chip, *_chain_shapes(CK.MAX_ATOMS, R_MAIN))
+
+
+def test_dense_nonbonded_compiles_v5e(one_chip):
+    """The main path's nonbonded kernel at the paper's 2,881 atoms."""
+    n_pad = pad_to_block(N_PAPER, LANE)
+    _compile(lambda c, m: LK.nonbonded_kernel_batched(
+        c, m, coulomb=COULOMB, block=LANE, interpret=False),
+        one_chip, ((R_MAIN, 8, n_pad), jnp.float32),
+        ((n_pad, n_pad), jnp.float32))
+
+
+def test_sparse_nonbonded_compiles_v5e_at_limit(one_chip):
+    """At the limit, with the largest neighbor capacity (every atom)."""
+    n_pad = pad_to_block(LK.SPARSE_MAX_ATOMS, LANE)
+    _compile(lambda c, i, v: LK.nonbonded_sparse_kernel_batched(
+        c, i, v, coulomb=COULOMB, cutoff=9.0, interpret=False),
+        one_chip, ((8, 8, n_pad), jnp.float32),
+        ((8, n_pad, n_pad), jnp.int32), ((8, n_pad, n_pad), jnp.float32))
+
+
+def test_fused_propagate_compiles_v5e_at_limit(one_chip):
+    n_pad = pad_to_block(FK.MAX_ATOMS, LANE)
+    shapes = _chain_shapes(FK.MAX_ATOMS, 1)[1:5]
+    f32 = jnp.float32
+    _compile(lambda c, v, z, st, bi, p, b, a, q, m, ms:
+             FK.fused_baoab_kernel_batched(
+                 c, v, z, st, bi, p, b, a, q, m, ms, tb=LANE, bias=True,
+                 coulomb=COULOMB, c1=0.99, half_kick=0.1, half_dt=2.5e-4,
+                 interpret=False),
+             one_chip, *([((8, 8, n_pad), f32)] * 3),
+             ((8, 1, 8), f32), ((8, 1, 8), f32), *shapes,
+             ((n_pad, n_pad), f32), ((8, n_pad), f32))
+
+
+def test_exchange_matrix_compiles_v5e(one_chip):
+    """The paper's largest ladder, 1,728 replicas, padded to the tile."""
+    r = pad_to_block(1728, LANE)
+    _compile(lambda f, g: XK.exchange_matrix_kernel(f, g, interpret=False),
+             one_chip, ((8, r), jnp.float32), ((8, r), jnp.float32))
+
+
+@pytest.mark.parametrize("force_path,nonbonded,limit", [
+    ("pallas", "dense", CK.MAX_ATOMS),
+    ("pallas", "sparse", LK.SPARSE_MAX_ATOMS),
+    ("fused", "dense", FK.MAX_ATOMS),
+])
+def test_engine_refuses_systems_beyond_kernel_limits(
+        monkeypatch, force_path, nonbonded, limit):
+    """On a TPU the engine refuses, at construction, a system its
+    compiled kernels cannot hold, naming the limit — it never falls
+    back to the jnp passes.  The backend is steered to "TPU, compiled"
+    here; the system is a bare atom count, since the check runs before
+    any topology is packed."""
+    import repro.md.engine as engine_mod
+    from repro.md import MDEngine
+    monkeypatch.setattr(engine_mod, "default_use_kernel", lambda: True)
+    monkeypatch.setattr(engine_mod, "default_interpret", lambda: False)
+    with pytest.raises(ValueError, match=f"at most {limit} atoms"):
+        MDEngine(system=types.SimpleNamespace(n_atoms=limit + 1),
+                 force_path=force_path, nonbonded=nonbonded)
