@@ -355,23 +355,6 @@ def cycle_fusion(rows: List[str]):
             "recovered_runtime_overhead_us_per_cycle": recovered,
         }
 
-        # one separately-instrumented pass at K_max: the telemetry
-        # probes decompose the cycle into Eq. (1)'s terms, so the JSON
-        # carries the phase split instead of an opaque total.  The
-        # stopwatch sweeps above stay un-instrumented — probe fences
-        # would perturb the very numbers they annotate.
-        from repro.obs import Telemetry
-        tel = Telemetry(phase_probe_every=1)
-        d = REMDDriver(eng, cfg, telemetry=tel)
-        d.run_fused(d.init(), n_cycles=n_cycles, chunk_cycles=k_max)
-        tel.reset()                      # drop the compile-bearing pass
-        d.run_fused(d.init(), n_cycles=n_cycles, chunk_cycles=k_max)
-        split = d.last_report.to_dict()["phases"]
-        payload["engines"][name]["phase_split"] = split
-        eq1 = split["eq1"]
-        rows.append(
-            f"cycle_fusion_{name}_eq1_split,{split['t_cycle_mean'] * 1e6:.0f},"
-            + "|".join(f"{t}={eq1[t] * 1e6:.0f}us" for t in sorted(eq1)))
     with open(JSON_OUT or "BENCH_cycle_fusion.json", "w") as f:
         json.dump(payload, f, indent=2)
 

@@ -66,9 +66,10 @@ class SimulationEngine(Protocol):
 #       its proposed ctrl assignment.  Engines whose energy factors into
 #       ctrl-independent features (the expensive O(N^2) part) times a
 #       cheap ctrl reduction should implement ``energy_pair`` to compute
-#       the features once; ``repro.core.exchange.pair_energies``
-#       dispatches to it when present and falls back to two ``energy``
-#       calls otherwise.
+#       the features once, through the split form below:
+#       ``repro.core.exchange.pair_energies`` calls
+#       ``energy_pair_from_features(replica_features(state), ...)`` when
+#       the engine has the split form and two ``energy`` calls otherwise.
 #
 #   def replica_features(self, state) -> feature pytree (leaves (R, ...))
 #   def energy_pair_from_features(self, feats, ctrl_a, ctrl_b)

@@ -73,16 +73,31 @@ def metropolis(delta: jax.Array, rng: jax.Array) -> jax.Array:
     return u < jnp.exp(jnp.minimum(-delta, 0.0))
 
 
+def replica_features(engine, state, gather=None):
+    """The engine's feature pass (on the sharded gather wire, followed by
+    ``gather`` on every feature row), under the ``features`` scope of a
+    device trace (docs/OBSERVABILITY.md)."""
+    with jax.named_scope("features"):
+        feats = engine.replica_features(state)
+        return feats if gather is None else jax.tree.map(gather, feats)
+
+
+def _split_api(engine, reduce: str) -> bool:
+    return (callable(getattr(engine, "replica_features", None))
+            and callable(getattr(engine, reduce, None)))
+
+
 def pair_energies(engine, state, ctrl_self: Dict, ctrl_swap: Dict
                   ) -> Tuple[jax.Array, jax.Array]:
     """Reduced energies under the current and the swapped ctrl assignment.
 
-    Engines exposing ``energy_pair`` evaluate both assignments from ONE
-    feature pass (the O(N^2) pair sums are ctrl-independent); others fall
-    back to two full ``energy`` calls.
+    Engines exposing the split feature API evaluate both assignments from
+    ONE feature pass (the O(N^2) pair sums are ctrl-independent); others
+    fall back to two full ``energy`` calls.
     """
-    if hasattr(engine, "energy_pair"):
-        return engine.energy_pair(state, ctrl_self, ctrl_swap)
+    if _split_api(engine, "energy_pair_from_features"):
+        return engine.energy_pair_from_features(
+            replica_features(engine, state), ctrl_self, ctrl_swap)
     return (engine.energy(state, ctrl_self),
             engine.energy(state, ctrl_swap))
 
@@ -254,7 +269,7 @@ def neighbor_exchange_sharded(
     ctrl_keys = getattr(engine, "ctrl_keys", None)
     ctrl_self = ctrl_for_assignment(grid, assignment, ctrl_keys)
     ctrl_swap = ctrl_for_assignment(grid, swapped, ctrl_keys)
-    feats = engine.replica_features(state)
+    feats = replica_features(engine, state)
     u_self_loc, u_swap_loc = engine.energy_pair_from_features(
         feats, jax.tree.map(sl, ctrl_self), jax.tree.map(sl, ctrl_swap))
 
@@ -295,11 +310,13 @@ def matrix_exchange(
     (``engine.cross_energy_from_features``).
     """
     n = assignment.shape[0]
+    ctrl_grid = {k: v for k, v in grid.values.items()}
+    if features is None and _split_api(engine, "cross_energy_from_features"):
+        features = replica_features(engine, state)
     if features is not None:
-        u = engine.cross_energy_from_features(
-            features, {k: v for k, v in grid.values.items()})
+        u = engine.cross_energy_from_features(features, ctrl_grid)
     else:
-        u = engine.cross_energy(state, {k: v for k, v in grid.values.items()})
+        u = engine.cross_energy(state, ctrl_grid)
     if fail is None:
         fail = engine.is_failed(state)
 
@@ -364,7 +381,7 @@ def matrix_exchange_sharded(
 
     fail = ring_all_gather(engine.is_failed(state), axis_name,
                            n_shards).reshape(n)
-    feats = engine.replica_features(state)
+    feats = replica_features(engine, state)
     tile = engine.cross_energy_from_features(
         feats, {k: v for k, v in grid.values.items()})   # (B, C) local tile
 

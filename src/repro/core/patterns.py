@@ -67,7 +67,7 @@ from repro.core.controls import ControlGrid, ctrl_for_assignment
 from repro.core.ensemble import Ensemble
 from repro.core.exchange import (matrix_exchange, matrix_exchange_sharded,
                                  neighbor_exchange,
-                                 neighbor_exchange_sharded)
+                                 neighbor_exchange_sharded, replica_features)
 
 
 def _propagate(engine, ens: Ensemble, grid: ControlGrid, n_steps, rng,
@@ -148,45 +148,49 @@ def _cycle_core(engine, grid: ControlGrid, ens: Ensemble, *, pattern: str,
     exchange_stats, ready_mask, fail_row) — ``fail_row`` is the
     replicated (R,) failure mask when sharded (reused by failure
     recovery so it never re-gathers), else None.
+
+    The phases run under the scopes ``propagate``, ``features`` and
+    ``exchange`` of a device trace: op metadata only, the compiled
+    program is unchanged (docs/OBSERVABILITY.md).
     """
     k_md, k_ex, k_next = jax.random.split(ens.rng, 3)
 
-    if pattern == "asynchronous":
-        max_steps = 2 * window_steps
-        n_steps = jnp.clip(
-            jnp.round(window_steps * ens.speed).astype(jnp.int32),
-            1, max_steps)
-    else:
-        max_steps = md_steps
-        n_steps = jnp.full(ens.assignment.shape, md_steps, jnp.int32)
+    with jax.named_scope("propagate"):
+        if pattern == "asynchronous":
+            max_steps = 2 * window_steps
+            n_steps = jnp.clip(
+                jnp.round(window_steps * ens.speed).astype(jnp.int32),
+                1, max_steps)
+        else:
+            max_steps = md_steps
+            n_steps = jnp.full(ens.assignment.shape, md_steps, jnp.int32)
+        if axis_name is None:
+            state = _propagate(engine, ens, grid, n_steps, k_md, execution,
+                               max_steps, mesh)
+        else:
+            state = _propagate_sharded(engine, ens, grid, n_steps, k_md,
+                                       execution, max_steps, axis_name,
+                                       n_shards)
 
-    halo_axis = None
-    if axis_name is None:
-        state = _propagate(engine, ens, grid, n_steps, k_md, execution,
-                           max_steps, mesh)
-        features = fail = None
-    else:
-        state = _propagate_sharded(engine, ens, grid, n_steps, k_md,
-                                   execution, max_steps, axis_name,
-                                   n_shards)
+    halo_axis = gather = features = None
+    if axis_name is not None:
         if exchange_comm == "gather":
             # legacy PR-5 wire: all-gather the (R,)-per-field feature
             # rows and the (R,) failure mask, recompute the reduction
             # replicated (the exchange_scaling A/B baseline)
             gather = functools.partial(jax.lax.all_gather,
                                        axis_name=axis_name, tiled=True)
-            features = jax.tree.map(gather, engine.replica_features(state))
-            fail = gather(engine.is_failed(state))
+            features = replica_features(engine, state, gather)
         else:
             # halo wire: the sharded exchange variants reduce the local
             # block themselves and ring only O(B) exchange scalars +
             # failure flags per sweep — positions, features and neighbor
             # lists stay shard-local (HLO census: collective-permutes
             # only, tests/test_sharded.py)
-            features = fail = None
             halo_axis = axis_name
 
     def run_exchange(ready):
+        fail = None if gather is None else gather(engine.is_failed(state))
         out = _exchange(engine, state, grid, ens.assignment, dim_index,
                         parity, k_ex, scheme, ready=ready,
                         features=features, fail=fail,
@@ -195,18 +199,20 @@ def _cycle_core(engine, grid: ControlGrid, ens: Ensemble, *, pattern: str,
             return out                      # (assignment, stats, fail_row)
         return out + (fail,)                # gather-mode fail row (or None)
 
-    if pattern == "asynchronous":
-        debt = ens.debt + n_steps.astype(jnp.float32)
-        ready = (debt >= md_steps) & ens.alive
-        assignment, stats, fail_row = run_exchange(ready)
-        debt = jnp.where(ready, debt - md_steps, debt)
-        new_ens = ens._replace(state=state, assignment=assignment,
-                               rng=k_next, cycle=ens.cycle + 1, debt=debt)
-    else:
-        ready = ens.alive
-        assignment, stats, fail_row = run_exchange(ready)
-        new_ens = ens._replace(state=state, assignment=assignment,
-                               rng=k_next, cycle=ens.cycle + 1)
+    with jax.named_scope("exchange"):
+        if pattern == "asynchronous":
+            debt = ens.debt + n_steps.astype(jnp.float32)
+            ready = (debt >= md_steps) & ens.alive
+            assignment, stats, fail_row = run_exchange(ready)
+            debt = jnp.where(ready, debt - md_steps, debt)
+            new_ens = ens._replace(state=state, assignment=assignment,
+                                   rng=k_next, cycle=ens.cycle + 1,
+                                   debt=debt)
+        else:
+            ready = ens.alive
+            assignment, stats, fail_row = run_exchange(ready)
+            new_ens = ens._replace(state=state, assignment=assignment,
+                                   rng=k_next, cycle=ens.cycle + 1)
     return new_ens, stats, ready, fail_row
 
 

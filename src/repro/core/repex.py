@@ -90,6 +90,7 @@ from repro.core.ensemble import Ensemble, make_ensemble
 from repro.core.modes import auto_mode
 from repro.ckpt import CheckpointError, CheckpointManager, load_checkpoint
 from repro.obs import build_report
+from repro.obs.spans import span
 
 # checkpoint 'extra' schema carried alongside the ensemble payload (the
 # host-side driver state resume() restores); bump when the layout changes
@@ -140,9 +141,11 @@ class REMDDriver:
         # differs from an un-instrumented driver (tests/test_telemetry).
         self.telemetry = telemetry
         self.last_report = None
-        self._phase_probes = None
-        self._probe_warmed: set = set()
         self._wire_budgets: Dict[int, Any] = {}
+        # host-span bookkeeping: chunks run over the driver's lifetime,
+        # and the chunk functions that have run at least once
+        self._chunks_run = 0
+        self._dispatched: set = set()
         # (backup, fail_key) restored by resume()/restore(), consumed by
         # the next run*() call so the scan carry continues bit-exactly
         self._resume_carry = None
@@ -162,19 +165,6 @@ class REMDDriver:
         of every compiled-fn cache key that consumes it."""
         t = self._tel
         return bool(t is not None and t.exchange_counters)
-
-    def _maybe_phase_sample(self, ens, cyc: int) -> None:
-        """Chunk-boundary phase probe: time each cycle phase standalone
-        on the CURRENT ensemble (JAX arrays are immutable — probes read,
-        never advance, so the trajectory is bitwise unchanged)."""
-        tel = self._tel
-        if tel is None or not tel.want_phase_sample():
-            return
-        from repro.obs import make_phase_probes, sample_phases
-        if self._phase_probes is None:
-            self._phase_probes = make_phase_probes(self)
-        times = sample_phases(self._phase_probes, ens, self._probe_warmed)
-        tel.note_phase_sample(cyc, times)
 
     # -- compiled cycle factory (one per dim x parity x pattern) ----------
 
@@ -301,7 +291,6 @@ class REMDDriver:
 
             tel = self._tel
             if tel is not None:
-                self._maybe_phase_sample(ens, cyc)
                 tel.note_cycles(
                     cycles=[cyc], dims=[dim_index],
                     assignments=assignment[None],
@@ -346,10 +335,11 @@ class REMDDriver:
         def one_cycle(carry, _):
             ens, backup, fail_key = carry
             if inject:
-                fail_key, k = jax.random.split(fail_key)
-                ens = F.inject_failures(ens, k, self.failure_rate,
-                                        axis_name=axis_name,
-                                        n_shards=n_shards)
+                with jax.named_scope("inject"):
+                    fail_key, k = jax.random.split(fail_key)
+                    ens = F.inject_failures(ens, k, self.failure_rate,
+                                            axis_name=axis_name,
+                                            n_shards=n_shards)
             cyc = ens.cycle
             new_ens, stats = patterns.fused_cycle(
                 self.engine, self.grid, ens, pattern=cfg.pattern,
@@ -361,15 +351,16 @@ class REMDDriver:
                 exchange_comm=cfg.exchange_comm,
                 telemetry_rows=obs_rows)
             fail_row = stats.pop("_fail_row", None)
-            if sharded:
-                new_ens, backup, esc = F.detect_recover_sharded(
-                    self.engine, new_ens, policy, backup, axis_name,
-                    n_shards, fail_row=fail_row,
-                    relaunch_budget=cfg.relaunch_budget)
-            else:
-                new_ens, backup, esc = F.detect_recover(
-                    self.engine, new_ens, policy, backup,
-                    relaunch_budget=cfg.relaunch_budget)
+            with jax.named_scope("detect_recover"):
+                if sharded:
+                    new_ens, backup, esc = F.detect_recover_sharded(
+                        self.engine, new_ens, policy, backup, axis_name,
+                        n_shards, fail_row=fail_row,
+                        relaunch_budget=cfg.relaunch_budget)
+                else:
+                    new_ens, backup, esc = F.detect_recover(
+                        self.engine, new_ens, policy, backup,
+                        relaunch_budget=cfg.relaunch_budget)
             ys = dict(stats, cycle=cyc, **esc)
             return (new_ens, backup, fail_key), ys
 
@@ -409,11 +400,14 @@ class REMDDriver:
         """
         if chunk_cycles < 1:
             raise ValueError(f"chunk_cycles must be >= 1, got {chunk_cycles}")
-        backup, fail_key = self._start_carry(ens)
-        ens = self._chunk_loop(ens, backup, fail_key,
+        with span("start"):
+            backup, fail_key = self._start_carry(ens)
+            c0 = int(jax.device_get(ens.cycle))
+        ens = self._chunk_loop(ens, backup, fail_key, c0,
                                n_cycles or self.cfg.n_cycles, chunk_cycles,
                                verbose, self._fused_chunk_fn)
-        self.last_report = build_report(self, "fused", chunk_cycles)
+        with span("report"):
+            self.last_report = build_report(self, "fused", chunk_cycles)
         return ens
 
     # -- replica-sharded multi-device path --------------------------------
@@ -534,106 +528,129 @@ class REMDDriver:
                 f"repro.core.engine optional extensions)")
 
         shardings = ensemble_shardings(mesh, ens)
-        ens = jax.device_put(ens, shardings)
-        # a resumed carry may live on the host / a DIFFERENT mesh (elastic
-        # restart): place it like a fresh one — backup shards with the
-        # state, the failure key is replicated
-        backup, fail_key = self._start_carry(ens)
-        backup = jax.device_put(backup, shardings.state)
-        fail_key = jax.device_put(fail_key, NamedSharding(mesh, P()))
+        with span("start"):
+            ens = jax.device_put(ens, shardings)
+            # a resumed carry may live on the host / a DIFFERENT mesh
+            # (elastic restart): place it like a fresh one — backup
+            # shards with the state, the failure key is replicated
+            backup, fail_key = self._start_carry(ens)
+            backup = jax.device_put(backup, shardings.state)
+            fail_key = jax.device_put(fail_key, NamedSharding(mesh, P()))
+            c0 = int(jax.device_get(ens.cycle))
         ens = self._chunk_loop(
-            ens, backup, fail_key, n_cycles or self.cfg.n_cycles,
+            ens, backup, fail_key, c0, n_cycles or self.cfg.n_cycles,
             chunk_cycles, verbose,
             lambda k: self._sharded_chunk_fn(k, mesh, ens))
-        self.last_report = build_report(self, "sharded", chunk_cycles)
+        with span("report"):
+            self.last_report = build_report(self, "sharded", chunk_cycles)
         return ens
 
     # -- the chunked host loop shared by run_fused / run_sharded ----------
 
-    def _chunk_loop(self, ens: Ensemble, backup, fail_key,
+    def _chunk_loop(self, ens: Ensemble, backup, fail_key, c0: int,
                     n_cycles: int, chunk_cycles: int, verbose: bool,
                     step_for) -> Ensemble:
-        """Drive ``step_for(k)`` chunk functions to ``n_cycles``, fetching
-        stats once per chunk and keeping ``history``/``acceptance``/
-        checkpoint bookkeeping identical across the fused and sharded
-        paths."""
-        c0 = int(jax.device_get(ens.cycle))
+        """Drive ``step_for(k)`` chunk functions for ``n_cycles`` cycles
+        from cycle ``c0``, fetching stats once per chunk and keeping
+        ``history``/``acceptance``/checkpoint bookkeeping identical across
+        the fused and sharded paths.  Each chunk runs inside the host
+        spans of ``repro.obs.spans``."""
         done = 0
         while done < n_cycles:
             k = min(chunk_cycles, n_cycles - done)
+            with span("chunk", chunk=self._chunks_run, cycles=k):
+                ens, backup, fail_key = self._one_chunk(
+                    ens, backup, fail_key, c0 + done, k, verbose,
+                    step_for)
+            self._chunks_run += 1
+            done += k
+        return ens
+
+    def _one_chunk(self, ens: Ensemble, backup, fail_key, c_start: int,
+                   k: int, verbose: bool, step_for):
+        """One K-cycle chunk from cycle ``c_start``: dispatch, wait, the
+        one stats fetch, the host bookkeeping, a due checkpoint."""
+        with span("dispatch") as dispatch:
             step = step_for(k)
+            dispatch.set_metadata(
+                first_call=id(step) not in self._dispatched)
+            self._dispatched.add(id(step))
             t0 = time.perf_counter()
             ens, backup, fail_key, ys = step(ens, backup, fail_key)
+        with span("wait"):
             jax.block_until_ready(ens.assignment)
-            t_chunk = time.perf_counter() - t0      # K x (T_MD + T_EX)
+        t_chunk = time.perf_counter() - t0          # K x (T_MD + T_EX)
 
-            t1 = time.perf_counter()
+        t1 = time.perf_counter()
+        with span("fetch"):
             ys = jax.device_get(ys)                 # ONE fetch per chunk
-            t_data = time.perf_counter() - t1
+        t_data = time.perf_counter() - t1
 
-            # batch-convert the (K,) stat arrays once; per-cycle history
-            # entries are then plain python — the bookkeeping stays O(K)
-            # cheap instead of K x numpy-scalar boxing
-            dims = ys["dim"].tolist()
-            acc = ys["accepted"].tolist()
-            att = ys["attempted"].tolist()
-            cycles = ys["cycle"].tolist()
-            failed = ys["failed"].tolist()
-            esc_rel = ys["esc_relaunch"].tolist()
-            esc_rei = ys["esc_reinit"].tolist()
-            esc_dead = ys["esc_dead"].tolist()
-            rfrac = ys["ready_frac"].tolist()
-            overfl = ys["nb_overflow"].tolist()
-            rebuilds = ys["nb_rebuilds"].tolist()
-            assignment = ys["assignment"]          # (K, R) int32
-            t_step, t_d = t_chunk / k, t_data / k
-            for i in range(k):
-                dkey = f"dim{dims[i]}"
-                bucket = self.acceptance[dkey]
-                bucket[0] += acc[i]
-                bucket[1] += att[i]
-                self.history.append({
-                    "cycle": cycles[i], "dim": dims[i],
-                    "t_step": t_step, "t_prep": 0.0,
-                    "t_recover": 0.0, "t_data": t_d,
-                    "accept": acc[i], "attempt": att[i],
-                    "failed": failed[i], "esc_relaunch": esc_rel[i],
-                    "esc_reinit": esc_rei[i], "esc_dead": esc_dead[i],
-                    "ready_frac": rfrac[i],
-                    "assignment": assignment[i],
-                    "nb_overflow": overfl[i],
-                    "nb_rebuilds": rebuilds[i],
-                })
-            done += k
+        with span("bookkeep"):
+            self._note_chunk(ys, k, t_chunk, t_data)
 
-            tel = self._tel
-            if tel is not None:
-                # phase probe first: want_phase_sample keys off the
-                # chunk counter BEFORE note_cycles increments it, so
-                # every Nth chunk boundary (including the first) samples
-                self._maybe_phase_sample(ens, c0 + done - 1)
-                budget = self._wire_budgets.get(k)
-                if budget is not None and tel.wire_ledger:
-                    tel.note_wire_budget(k, budget)
-                    tel.note_wire_invocation(k)
-                tel.note_cycles(
-                    cycles=cycles, dims=dims, assignments=assignment,
-                    n_dims=len(self.grid.dims), n_ctrl=self.grid.n_ctrl,
-                    pair_attempt=ys.get("pair_attempt"),
-                    pair_accept=ys.get("pair_accept"),
-                    t_cycle=t_chunk, t_data=t_data)
-
-            if self.ckpt is not None and self.ckpt.every > 0:
-                lo, hi = c0 + done - k, c0 + done - 1
-                if hi // self.ckpt.every > (lo - 1) // self.ckpt.every:
+        if self.ckpt is not None and self.ckpt.every > 0:
+            lo, hi = c_start, c_start + k - 1
+            if hi // self.ckpt.every > (lo - 1) // self.ckpt.every:
+                with span("ckpt"):
                     self._save_ckpt(hi, ens, backup, fail_key, force=True)
-            if verbose:
-                acc = sum(float(a) for a in ys["accepted"])
-                att = max(sum(float(a) for a in ys["attempted"]), 1.0)
-                print(f"chunk @cycle {c0 + done:4d} K={k} "
-                      f"acc {acc / att * 100:5.1f}%  "
-                      f"t {t_chunk / k * 1e3:7.2f} ms/cycle")
-        return ens
+        if verbose:
+            acc = sum(float(a) for a in ys["accepted"])
+            att = max(sum(float(a) for a in ys["attempted"]), 1.0)
+            print(f"chunk @cycle {c_start + k:4d} K={k} "
+                  f"acc {acc / att * 100:5.1f}%  "
+                  f"t {t_chunk / k * 1e3:7.2f} ms/cycle")
+        return ens, backup, fail_key
+
+    def _note_chunk(self, ys, k: int, t_chunk: float, t_data: float):
+        """Fold one chunk's fetched (K,) stats into ``history``,
+        ``acceptance`` and the telemetry accumulator."""
+        # batch-convert the (K,) stat arrays once; per-cycle history
+        # entries are then plain python — the bookkeeping stays O(K)
+        # cheap instead of K x numpy-scalar boxing
+        dims = ys["dim"].tolist()
+        acc = ys["accepted"].tolist()
+        att = ys["attempted"].tolist()
+        cycles = ys["cycle"].tolist()
+        failed = ys["failed"].tolist()
+        esc_rel = ys["esc_relaunch"].tolist()
+        esc_rei = ys["esc_reinit"].tolist()
+        esc_dead = ys["esc_dead"].tolist()
+        rfrac = ys["ready_frac"].tolist()
+        overfl = ys["nb_overflow"].tolist()
+        rebuilds = ys["nb_rebuilds"].tolist()
+        assignment = ys["assignment"]              # (K, R) int32
+        t_step, t_d = t_chunk / k, t_data / k
+        for i in range(k):
+            dkey = f"dim{dims[i]}"
+            bucket = self.acceptance[dkey]
+            bucket[0] += acc[i]
+            bucket[1] += att[i]
+            self.history.append({
+                "cycle": cycles[i], "dim": dims[i],
+                "t_step": t_step, "t_prep": 0.0,
+                "t_recover": 0.0, "t_data": t_d,
+                "accept": acc[i], "attempt": att[i],
+                "failed": failed[i], "esc_relaunch": esc_rel[i],
+                "esc_reinit": esc_rei[i], "esc_dead": esc_dead[i],
+                "ready_frac": rfrac[i],
+                "assignment": assignment[i],
+                "nb_overflow": overfl[i],
+                "nb_rebuilds": rebuilds[i],
+            })
+
+        tel = self._tel
+        if tel is not None:
+            budget = self._wire_budgets.get(k)
+            if budget is not None and tel.wire_ledger:
+                tel.note_wire_budget(k, budget)
+                tel.note_wire_invocation(k)
+            tel.note_cycles(
+                cycles=cycles, dims=dims, assignments=assignment,
+                n_dims=len(self.grid.dims), n_ctrl=self.grid.n_ctrl,
+                pair_attempt=ys.get("pair_attempt"),
+                pair_accept=ys.get("pair_accept"),
+                t_cycle=t_chunk, t_data=t_data)
 
     def acceptance_ratios(self) -> Dict[str, float]:
         return {k: (a / max(n, 1.0))
@@ -652,8 +669,9 @@ class REMDDriver:
         budget = self.cfg.relaunch_budget
 
         def step(ens, backup):
-            return F.detect_recover(self.engine, ens, policy, backup,
-                                    relaunch_budget=budget)
+            with jax.named_scope("detect_recover"):
+                return F.detect_recover(self.engine, ens, policy, backup,
+                                        relaunch_budget=budget)
 
         jitted = jax.jit(step)
         self._compiled[key] = jitted

@@ -298,5 +298,6 @@ def chain_forces_kernel_batched(coords, gmat, bond_par, ang_par, quad_par,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_CAP_BYTES),
+        name="chain_bonded",
         interpret=interpret,
     )(coords, gmat, bond_par, ang_par, quad_par, bias_par)

@@ -54,5 +54,6 @@ def exchange_matrix_kernel(feat, ctrl, *, block_r: int = 128,
                   pl.BlockSpec((8, block_c), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, c), jnp.float32),
+        name="exchange_matrix",
         interpret=interpret,
     )(feat, ctrl)

@@ -113,5 +113,6 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)

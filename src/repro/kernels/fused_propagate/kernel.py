@@ -137,6 +137,7 @@ def fused_baoab_kernel_batched(coords, vels, noise, step_par, bias_par,
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_CAP_BYTES),
+        name="fused_propagate",
         interpret=interpret,
     )(coords, vels, noise, step_par, bias_par, gmat, bond_par, ang_par,
       quad_par, nb_mask, mass_rows)

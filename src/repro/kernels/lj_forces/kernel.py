@@ -111,6 +111,7 @@ def lj_energy_kernel_batched(coords, *, sigma: float, eps: float,
                   pl.BlockSpec((1, 8, block), lambda q, i, j: (q, 0, j))],
         out_specs=pl.BlockSpec((1, 1, 1), lambda q, i, j: (q, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((r, 1, 1), jnp.float32),
+        name="lj_energy",
         interpret=interpret,
     )(coords, coords)
     return out[:, 0, 0]
@@ -133,6 +134,7 @@ def lj_forces_kernel_batched(coords, *, sigma: float, eps: float,
                   pl.BlockSpec((1, 8, block), lambda q, i, j: (q, 0, j))],
         out_specs=pl.BlockSpec((1, 8, block), lambda q, i, j: (q, 0, i)),
         out_shape=jax.ShapeDtypeStruct((r, 8, n), jnp.float32),
+        name="lj_forces",
         interpret=interpret,
     )(coords, coords)
 
@@ -287,6 +289,7 @@ def nonbonded_sparse_kernel_batched(coords, idx, valid, *, coulomb: float,
                    jax.ShapeDtypeStruct((r, 1, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_CAP_BYTES),
+        name="lj_nonbonded_sparse",
         interpret=interpret,
     )(coords, idx, valid)
 
@@ -314,5 +317,6 @@ def nonbonded_kernel_batched(coords, nb_mask, *, coulomb: float,
         out_shape=[jax.ShapeDtypeStruct((r, 8, n), jnp.float32),
                    jax.ShapeDtypeStruct((r, 1, 1), jnp.float32),
                    jax.ShapeDtypeStruct((r, 1, 1), jnp.float32)],
+        name="lj_nonbonded_dense",
         interpret=interpret,
     )(coords, coords, nb_mask)

@@ -44,8 +44,9 @@ def make_replica_mesh(n_shards: int = 0):
             f"XLA_FLAGS=--xla_force_host_platform_device_count=N before "
             f"jax initializes)")
     # Auto axis: arrays on this mesh carry no sharding in their types, so
-    # jitted code outside the shard_map (the telemetry phase probes,
-    # the chunk-boundary bookkeeping) partitions them by propagation
+    # jitted code outside the shard_map (the start-of-run device_put and
+    # cycle fetch, the chunk-boundary bookkeeping) partitions them by
+    # propagation
     return jax.make_mesh((n_shards,), ("replica",),
                          axis_types=(jax.sharding.AxisType.Auto,))
 

@@ -11,11 +11,17 @@ configuration files' usability requirement):
   python -m repro.launch.repex_run --dims temperature:8 --chunk 16
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       python -m repro.launch.repex_run --dims temperature:8 --shards 8
+  # a profiler trace of the run, for the Eq. (1) split
+  # (docs/OBSERVABILITY.md):
+  python -m repro.launch.repex_run --dims temperature:8 --chunk 4 \
+      --profile-dir /tmp/repex-trace
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 
+import jax
 import numpy as np
 
 from repro.config import RepExConfig
@@ -70,11 +76,13 @@ def main():
                          "(run_sharded; uses --chunk or 16)")
     ap.add_argument("--report-out", default=None, metavar="PATH",
                     help="write the structured RunReport JSON here "
-                         "(enables telemetry: per-pair counters, phase "
-                         "brackets, wire ledger — docs/OBSERVABILITY.md)")
-    ap.add_argument("--phase-probe-every", type=int, default=1,
-                    help="sample phase timings every Nth chunk boundary "
-                         "(0 = off; only with --report-out)")
+                         "(enables telemetry: per-pair counters, "
+                         "occupancy, wire ledger — docs/OBSERVABILITY.md)")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="record a profiler trace of the run into DIR: "
+                         "the cycle body's scopes and the driver's host "
+                         "spans give the Eq. (1) split "
+                         "(docs/OBSERVABILITY.md)")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -101,7 +109,7 @@ def main():
     telemetry = None
     if args.report_out:
         from repro.obs import Telemetry
-        telemetry = Telemetry(phase_probe_every=args.phase_probe_every)
+        telemetry = Telemetry()
     ckpt_dir = args.resume or args.ckpt_dir
     driver = REMDDriver(engine, cfg, slots=args.slots,
                         ckpt_dir=ckpt_dir,
@@ -110,6 +118,23 @@ def main():
                         telemetry=telemetry)
     print(f"replicas={driver.grid.n_ctrl} execution={driver.execution} "
           f"pattern={cfg.pattern} scheme={cfg.exchange_scheme}")
+    with (jax.profiler.trace(args.profile_dir) if args.profile_dir
+          else contextlib.nullcontext()):
+        ens = _run(driver, args)
+    print("\nmultiset ok:", control_multiset_ok(ens))
+    print("acceptance:", {k: f"{v*100:.1f}%"
+                          for k, v in driver.acceptance_ratios().items()})
+    print("failures recovered:", sum(h["failed"] for h in driver.history))
+    if args.report_out:
+        driver.last_report.save(args.report_out)
+        print(f"report -> {args.report_out}")
+    if args.profile_dir:
+        print(f"profile -> {args.profile_dir}")
+
+
+def _run(driver, args):
+    """The run the flags ask for: resumed, replica-sharded, fused or
+    per-cycle."""
     if args.resume:
         via = "sharded" if args.shards else ("fused" if args.chunk
                                              else "run")
@@ -117,31 +142,19 @@ def main():
         if args.shards:
             from repro.launch.mesh import make_replica_mesh
             mesh = make_replica_mesh(args.shards)
-        ens = driver.resume(via=via, n_cycles=args.cycles,
-                            chunk_cycles=args.chunk or 16, mesh=mesh,
-                            verbose=True)
-    elif args.shards:
+        return driver.resume(via=via, n_cycles=args.cycles,
+                             chunk_cycles=args.chunk or 16, mesh=mesh,
+                             verbose=True)
+    if args.shards:
         from repro.launch.mesh import make_replica_mesh
-        ens = driver.run_sharded(driver.init(),
-                                 mesh=make_replica_mesh(args.shards),
-                                 chunk_cycles=args.chunk or 16,
-                                 verbose=True)
-    elif args.chunk:
-        ens = driver.run_fused(driver.init(), chunk_cycles=args.chunk,
-                               verbose=True)
-    else:
-        ens = driver.run(driver.init(), verbose=True)
-    print("\nmultiset ok:", control_multiset_ok(ens))
-    print("acceptance:", {k: f"{v*100:.1f}%"
-                          for k, v in driver.acceptance_ratios().items()})
-    print("failures recovered:", sum(h["failed"] for h in driver.history))
-    if args.report_out:
-        driver.last_report.save(args.report_out)
-        eq1 = driver.last_report.phases["eq1"]
-        print(f"report -> {args.report_out}")
-        if eq1:
-            print("Eq.(1) split:",
-                  {k: f"{v*1e3:.3f} ms" for k, v in eq1.items()})
+        return driver.run_sharded(driver.init(),
+                                  mesh=make_replica_mesh(args.shards),
+                                  chunk_cycles=args.chunk or 16,
+                                  verbose=True)
+    if args.chunk:
+        return driver.run_fused(driver.init(), chunk_cycles=args.chunk,
+                                verbose=True)
+    return driver.run(driver.init(), verbose=True)
 
 
 if __name__ == "__main__":

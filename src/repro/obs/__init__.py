@@ -2,18 +2,18 @@
 
 :class:`Telemetry` (config + host accumulator) rides the fused cycle
 scan; :class:`RunReport` is the structured summary every driver path
-emits.  See docs/OBSERVABILITY.md for the Eq. (1) phase mapping and the
-observer-effect contract (telemetry off = identical HLO; telemetry on =
-bitwise-identical trajectory).
+emits; :func:`span` names the driver's host work in a profiler trace.
+See docs/OBSERVABILITY.md for the Eq. (1) mapping onto the trace's
+scopes and spans and the observer-effect contract (telemetry off =
+identical HLO; telemetry on = bitwise-identical trajectory).
 """
 from repro.obs.report import (REPORT_VERSION, RunReport, build_report,
                               validate_report)
-from repro.obs.telemetry import (PHASES, Telemetry, accumulate_occupancy,
-                                 make_phase_probes, round_trip_fold,
-                                 sample_phases)
+from repro.obs.spans import span
+from repro.obs.telemetry import (Telemetry, accumulate_occupancy,
+                                 round_trip_fold)
 
 __all__ = [
-    "PHASES", "REPORT_VERSION", "RunReport", "Telemetry",
-    "accumulate_occupancy", "build_report", "make_phase_probes",
-    "round_trip_fold", "sample_phases", "validate_report",
+    "REPORT_VERSION", "RunReport", "Telemetry", "accumulate_occupancy",
+    "build_report", "round_trip_fold", "span", "validate_report",
 ]

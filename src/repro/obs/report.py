@@ -4,9 +4,10 @@ One dataclass, JSON-serializable, built by :func:`build_report` from a
 driver (+ its optional :class:`~repro.obs.telemetry.Telemetry`
 accumulator) at the end of ``run()`` / ``run_fused()`` /
 ``run_sharded()`` and stored as ``driver.last_report``.  Consumers: the
-``repex_run`` CLI (``--report-out``), ``benchmarks/run.py`` (phase
-splits embedded in BENCH_*.json), and CI (schema validation via
-:func:`validate_report`).
+``repex_run`` CLI (``--report-out``) and CI (schema validation via
+:func:`validate_report`).  Phase times are not in it: the Eq. (1) split
+comes from a profiler trace (``repex_run --profile-dir``,
+docs/OBSERVABILITY.md).
 
 Schema (``docs/OBSERVABILITY.md`` is the narrative version):
 
@@ -15,8 +16,8 @@ Schema (``docs/OBSERVABILITY.md`` is the narrative version):
   cycles      {total, counted}            total = driver history rows;
                                           counted = cycles the telemetry
                                           counters cover (post-reset)
-  phases      {samples, means{...}, eq1{T_MD, T_EX, T_data,
-               T_RepEx_over, T_runtime_over}}   seconds; Eq. (1) mapping
+  phases      {t_cycle_mean, t_data_mean,     host-clock seconds per
+               t_prep_mean}                     cycle
   exchange    {attempted, accepted, rate, per_dim{...},
                pair_attempt, pair_accept,       (D, 2, W) nested lists or
                occupancy, round_trips}          null (matrix scheme / off)
@@ -41,7 +42,9 @@ import numpy as np
 
 # v2: failures section gained the escalation-ladder counters
 # (relaunched / reinit_peer / degraded)
-REPORT_VERSION = 2
+# v3: phases lost the probe samples, means and eq1 (the split is read
+# from a profiler trace instead)
+REPORT_VERSION = 3
 
 # top-level keys every report must carry (CI schema check)
 _REQUIRED = ("version", "path", "engine", "pattern", "scheme",
@@ -118,12 +121,6 @@ def validate_report(d: Dict[str, Any]) -> Dict[str, Any]:
                 problems.append(f"exchange missing {k!r}")
         if not problems and ex["accepted"] > ex["attempted"]:
             problems.append("accepted > attempted")
-        ph = d["phases"]
-        if "eq1" in ph and ph["eq1"] is not None:
-            for term in ("T_MD", "T_EX", "T_data", "T_RepEx_over",
-                         "T_runtime_over"):
-                if term not in ph["eq1"]:
-                    problems.append(f"phases.eq1 missing {term!r}")
         for k in ("nb_overflow", "nb_rebuilds"):
             if k not in d["neighbor"]:
                 problems.append(f"neighbor missing {k!r}")
@@ -135,28 +132,6 @@ def validate_report(d: Dict[str, Any]) -> Dict[str, Any]:
     return d
 
 
-def _eq1(phase_means: Dict[str, float], t_cycle: float, t_data: float,
-         t_prep: float) -> Optional[Dict[str, float]]:
-    """Map measured phase brackets onto the paper's Eq. (1) terms.
-
-    T_MD = propagate; T_EX = features + exchange (the exchange phase
-    including its energy reduction); T_data = host<->device fetch;
-    T_RepEx_over = host task prep; T_runtime_over = whatever of the
-    measured cycle wall time the brackets do not explain (dispatch /
-    launch overhead — clamped at 0 because probe samples and the cycle
-    mean come from different executions).
-    """
-    if not phase_means:
-        return None
-    t_md = phase_means.get("propagate", 0.0)
-    t_ex = (phase_means.get("features", 0.0)
-            + phase_means.get("exchange", 0.0))
-    t_rec = phase_means.get("detect_recover", 0.0)
-    t_over = max(t_cycle - (t_md + t_ex + t_rec), 0.0)
-    return {"T_MD": t_md, "T_EX": t_ex, "T_data": t_data,
-            "T_RepEx_over": t_prep, "T_runtime_over": t_over}
-
-
 def build_report(driver, path: str,
                  chunk_cycles: Optional[int] = None) -> RunReport:
     """Assemble a :class:`RunReport` from a driver's bookkeeping.
@@ -164,8 +139,8 @@ def build_report(driver, path: str,
     Works with or without a live telemetry accumulator: counters the
     telemetry did not collect (disabled, or ``telemetry=None``) fall
     back to what ``driver.history`` already carries — pair-resolved
-    counters, occupancy/round-trips, phase brackets and the wire ledger
-    are telemetry-only and reported as null/empty when absent.
+    counters, occupancy/round-trips and the wire ledger are
+    telemetry-only and reported as null/empty when absent.
     """
     import jax
 
@@ -212,14 +187,8 @@ def build_report(driver, path: str,
         t_prep = float(np.mean([h["t_prep"] for h in hist]))
     else:
         t_cycle = t_data = t_prep = 0.0
-    means = tel.phase_means() if tel is not None else {}
-    phases = {
-        "samples": len(tel.phase_samples) if tel is not None else 0,
-        "means": means,
-        "t_cycle_mean": t_cycle, "t_data_mean": t_data,
-        "t_prep_mean": t_prep,
-        "eq1": _eq1(means, t_cycle, t_data, t_prep),
-    }
+    phases = {"t_cycle_mean": t_cycle, "t_data_mean": t_data,
+              "t_prep_mean": t_prep}
 
     # -- failures / neighbor-list rollups --------------------------------
     failures = {

@@ -1,11 +1,14 @@
-"""On-device observability for REMD runs — the Eq. (1) instrumentation.
+"""On-device observability for REMD runs: the counters.
 
 The paper's performance argument decomposes cycle time as
 
     T_c = T_MD + T_EX + T_data + T_RepEx_over + T_runtime_over     (Eq. 1)
 
-but a fused K-cycle scan only ever shows the host their SUM.  This module
-splits it back apart without perturbing the run:
+and a fused K-cycle scan only ever shows the host their SUM.  The split
+comes from a profiler trace: the cycle body's ``jax.named_scope``s and
+the driver's host spans (``repro.obs.spans``) name each term
+(docs/OBSERVABILITY.md).  This module keeps the counters a trace cannot
+give, without perturbing the run:
 
   * **Exchange/wire counters** ride the fused cycle scan itself as extra
     per-cycle ys rows (``pair_attempt`` / ``pair_accept``, one row per
@@ -15,13 +18,6 @@ splits it back apart without perturbing the run:
     chunk, and when telemetry is OFF the rows are popped before the jit
     boundary so the compiled program is IDENTICAL (op-budget-pinned,
     tests/test_telemetry.py).
-  * **Phase timing brackets** are sampled at chunk boundaries: standalone
-    jitted probes of each phase (propagate / features / exchange /
-    detect-recover) run on the CURRENT ensemble between chunks, fenced by
-    ``block_until_ready``.  Probes are pure functions of immutable arrays
-    — they read the ensemble, never advance it — so the trajectory is
-    bitwise unchanged (the observer-effect contract,
-    docs/OBSERVABILITY.md).
   * **Rung occupancy / round trips** are folded on the host from the
     per-cycle ``assignment`` trace the driver already fetches (PR-4) —
     no extra device work at all.
@@ -30,7 +26,7 @@ splits it back apart without perturbing the run:
     the number of chunk invocations — measured bytes-per-collective for
     the run, attached to the :class:`~repro.obs.report.RunReport`.
 
-A :class:`Telemetry` instance is both the configuration (which probes
+A :class:`Telemetry` instance is both the configuration (which counters
 are on) and the host-side accumulator (cleared by :meth:`reset`, e.g.
 after a warm-up period).  ``REMDDriver(..., telemetry=Telemetry())``
 activates it; the default ``telemetry=None`` changes NOTHING — not one
@@ -38,13 +34,10 @@ compiled op (the off switch is a true no-op).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Optional
 
 import numpy as np
-
-PHASES = ("propagate", "features", "exchange", "detect_recover")
 
 
 def accumulate_occupancy(trace: np.ndarray, n_ctrl: int,
@@ -115,9 +108,6 @@ class Telemetry:
     # (neighbor/DEO scheme only — the Gibbs matrix scheme's pairings are
     # re-drawn per sweep, so a static pair-slot axis does not exist)
     exchange_counters: bool = True
-    # sample per-phase timings every Nth chunk boundary (0 = off).
-    # ``run()`` samples every Nth cycle.
-    phase_probe_every: int = 1
     # census the compiled sharded chunk's collectives (run_sharded only)
     wire_ledger: bool = True
 
@@ -127,15 +117,12 @@ class Telemetry:
     occupancy: Optional[np.ndarray] = field(default=None, repr=False)
     rt_phase: Optional[np.ndarray] = field(default=None, repr=False)
     round_trips: Optional[np.ndarray] = field(default=None, repr=False)
-    phase_samples: List[Dict[str, float]] = field(default_factory=list,
-                                                  repr=False)
     wire: Dict[int, Dict[str, Any]] = field(default_factory=dict,
                                             repr=False)
     n_cycles_seen: int = field(default=0, repr=False)
     t_cycle_total: float = field(default=0.0, repr=False)
     t_data_total: float = field(default=0.0, repr=False)
     t_prep_total: float = field(default=0.0, repr=False)
-    _chunks_seen: int = field(default=0, repr=False)
 
     # -- lifecycle --------------------------------------------------------
 
@@ -148,13 +135,11 @@ class Telemetry:
         self.occupancy = None
         self.rt_phase = None
         self.round_trips = None
-        self.phase_samples = []
         self.wire = {}
         self.n_cycles_seen = 0
         self.t_cycle_total = 0.0
         self.t_data_total = 0.0
         self.t_prep_total = 0.0
-        self._chunks_seen = 0
 
     # -- per-chunk / per-cycle feeding ------------------------------------
 
@@ -200,14 +185,6 @@ class Telemetry:
         self.t_cycle_total += t_cycle
         self.t_data_total += t_data
         self.t_prep_total += t_prep
-        self._chunks_seen += 1
-
-    def want_phase_sample(self) -> bool:
-        e = self.phase_probe_every
-        return bool(e) and (self._chunks_seen % e == 0)
-
-    def note_phase_sample(self, cycle: int, times: Dict[str, float]) -> None:
-        self.phase_samples.append({"cycle": int(cycle), **times})
 
     def note_wire_budget(self, chunk_cycles: int,
                          budget: Dict[str, Dict[str, int]]) -> None:
@@ -236,41 +213,28 @@ class Telemetry:
             a = getattr(self, f)
             out[f] = (None if a is None
                       else {"dtype": str(a.dtype), "data": a.tolist()})
-        out["phase_samples"] = list(self.phase_samples)
         out["wire"] = {str(k): v for k, v in self.wire.items()}
         out["n_cycles_seen"] = self.n_cycles_seen
         out["t_cycle_total"] = self.t_cycle_total
         out["t_data_total"] = self.t_data_total
         out["t_prep_total"] = self.t_prep_total
-        out["chunks_seen"] = self._chunks_seen
         return out
 
     def load_state_dict(self, d: Dict[str, Any]) -> None:
-        """Inverse of :meth:`state_dict` (config flags untouched)."""
+        """Inverse of :meth:`state_dict` (config flags untouched).  Keys
+        it does not know are ignored: an older checkpoint's
+        ``phase_samples`` and ``chunks_seen`` load."""
         for f in self._ARRAY_FIELDS:
             v = d.get(f)
             setattr(self, f, None if v is None
                     else np.asarray(v["data"], dtype=np.dtype(v["dtype"])))
-        self.phase_samples = list(d.get("phase_samples", []))
         self.wire = {int(k): v for k, v in d.get("wire", {}).items()}
         self.n_cycles_seen = int(d.get("n_cycles_seen", 0))
         self.t_cycle_total = float(d.get("t_cycle_total", 0.0))
         self.t_data_total = float(d.get("t_data_total", 0.0))
         self.t_prep_total = float(d.get("t_prep_total", 0.0))
-        self._chunks_seen = int(d.get("chunks_seen", 0))
 
     # -- summaries --------------------------------------------------------
-
-    def phase_means(self) -> Dict[str, float]:
-        """Mean seconds per phase over the collected probe samples."""
-        if not self.phase_samples:
-            return {}
-        out: Dict[str, float] = {}
-        for ph in PHASES:
-            vals = [s[ph] for s in self.phase_samples if ph in s]
-            if vals:
-                out[ph] = float(np.mean(vals))
-        return out
 
     def wire_totals(self) -> Dict[str, Dict[str, float]]:
         """Measured bytes per collective for the whole run: the static
@@ -285,106 +249,3 @@ class Telemetry:
                 t["count"] += b["count"] * inv
                 t["bytes"] += b["bytes"] * inv
         return totals
-
-
-# ---------------------------------------------------------------------------
-# Phase probes (chunk-boundary timing brackets)
-# ---------------------------------------------------------------------------
-
-
-def make_phase_probes(driver) -> Dict[str, Any]:
-    """Build the four jitted phase probes for a driver's configuration.
-
-    Each probe runs ONE phase of a cycle on an ensemble snapshot —
-    exactly the code the fused cycle body runs (same propagate mode,
-    same exchange scheme/sweep-table gather), but standalone so a
-    ``block_until_ready`` fence brackets that phase alone.  Probes take
-    the ensemble as an argument and return fresh arrays: they cannot
-    mutate the run (JAX arrays are immutable), so sampling them between
-    chunks leaves the trajectory bitwise unchanged.
-
-    For sharded runs the probes execute on the global (GSPMD-partitioned)
-    arrays outside the ``shard_map`` — per-phase times are then an
-    upper bound including any resharding XLA inserts; the wire ledger,
-    not the probe, is the communication truth.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core import failures as F
-    from repro.core import patterns
-    from repro.core.controls import ctrl_for_assignment
-
-    engine, grid, cfg = driver.engine, driver.grid, driver.cfg
-    execution = driver.execution
-    md_steps = cfg.md_steps_per_cycle
-    window_steps = max(int(md_steps * cfg.async_window), 1)
-    policy = "relaunch" if cfg.relaunch_failed else "continue"
-    has_features = driver.capabilities["replica_features"]
-
-    def _steps(ens):
-        if cfg.pattern == "asynchronous":
-            max_steps = 2 * window_steps
-            n_steps = jnp.clip(
-                jnp.round(window_steps * ens.speed).astype(jnp.int32),
-                1, max_steps)
-        else:
-            max_steps = md_steps
-            n_steps = jnp.full(ens.assignment.shape, md_steps, jnp.int32)
-        return n_steps, max_steps
-
-    def probe_propagate(ens):
-        k_md = jax.random.split(ens.rng, 3)[0]
-        n_steps, max_steps = _steps(ens)
-        return patterns._propagate(engine, ens, grid, n_steps, k_md,
-                                   execution, max_steps, driver.mesh)
-
-    def probe_features(ens):
-        if has_features:
-            return engine.replica_features(ens.state)
-        ctrl = ctrl_for_assignment(grid, ens.assignment,
-                                   getattr(engine, "ctrl_keys", None))
-        return engine.energy(ens.state, ctrl)
-
-    def probe_exchange(ens):
-        k_ex = jax.random.split(ens.rng, 3)[1]
-        n_dims = len(grid.dims)
-        dim_index = jnp.mod(ens.cycle, n_dims)
-        parity = jnp.mod(ens.cycle // n_dims, 2)
-        return patterns._exchange(engine, ens.state, grid, ens.assignment,
-                                  dim_index, parity, k_ex,
-                                  cfg.exchange_scheme, ready=ens.alive)
-
-    def probe_detect_recover(ens):
-        return F.detect_recover(engine, ens, policy, ens.state,
-                                relaunch_budget=cfg.relaunch_budget)
-
-    return {
-        "propagate": jax.jit(probe_propagate),
-        "features": jax.jit(probe_features),
-        "exchange": jax.jit(probe_exchange),
-        "detect_recover": jax.jit(probe_detect_recover),
-    }
-
-
-def sample_phases(probes: Dict[str, Any], ens,
-                  warmed: set) -> Dict[str, float]:
-    """Run each probe on ``ens`` and return wall seconds per phase.
-
-    The first execution of a probe compiles it — that call is used as
-    the warm-up and a second, compile-free call is the one timed
-    (``warmed`` tracks which probes have compiled; pass the same set
-    across samples).
-    """
-    import jax
-
-    out: Dict[str, float] = {}
-    for name in PHASES:
-        fn = probes[name]
-        if name not in warmed:
-            jax.block_until_ready(fn(ens))      # compile + warm
-            warmed.add(name)
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(ens))
-        out[name] = time.perf_counter() - t0
-    return out

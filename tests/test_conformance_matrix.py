@@ -350,8 +350,7 @@ def test_interaction_telemetry_invariance_fused_path(variant):
         kw.update(bonded="sparse", nonbonded="sparse")
     cfg = RepExConfig(dimensions=(("temperature", 4),),
                       md_steps_per_cycle=2, n_cycles=4)
-    d_on = REMDDriver(MDEngine(**kw), cfg,
-                      telemetry=Telemetry(phase_probe_every=1))
+    d_on = REMDDriver(MDEngine(**kw), cfg, telemetry=Telemetry())
     d_off = REMDDriver(MDEngine(**kw), cfg)
     d_on.run_fused(d_on.init(), chunk_cycles=2)
     d_off.run_fused(d_off.init(), chunk_cycles=2)

@@ -92,7 +92,7 @@ def harmonic_run():
                       n_cycles=N_CYCLES, seed=1)
     # gamma * dt * md_steps = 15: each cycle fully re-equilibrates
     eng = HarmonicEngine(n_dim=3, k_spring=K_SPRING, dt=0.05, gamma=5.0)
-    tel = Telemetry(phase_probe_every=0)      # counters only, no probes
+    tel = Telemetry()
     drv = REMDDriver(eng, cfg, telemetry=tel)
     ens = drv.init()
     xs, rungs = [], []
@@ -178,7 +178,7 @@ def test_pair_acceptance_wide_ladder():
                       t_max=600.0, md_steps_per_cycle=60,
                       n_cycles=2048, seed=3)
     eng = HarmonicEngine(n_dim=3, k_spring=K_SPRING, dt=0.05, gamma=5.0)
-    tel = Telemetry(phase_probe_every=0)
+    tel = Telemetry()
     drv = REMDDriver(eng, cfg, telemetry=tel)
     ens, done = drv.init(), 0
     while done < 2048:

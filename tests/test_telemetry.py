@@ -70,8 +70,7 @@ def _assert_same_trajectory(d_on, d_off):
 @pytest.mark.parametrize("pattern", ["synchronous", "asynchronous"])
 def test_fused_invariance(pattern, scheme):
     cfg = _cfg(pattern=pattern, scheme=scheme)
-    d_on = REMDDriver(HarmonicEngine(), cfg,
-                      telemetry=Telemetry(phase_probe_every=1))
+    d_on = REMDDriver(HarmonicEngine(), cfg, telemetry=Telemetry())
     d_off = REMDDriver(HarmonicEngine(), cfg)
     d_on.run_fused(d_on.init(), chunk_cycles=4)
     d_off.run_fused(d_off.init(), chunk_cycles=4)
@@ -105,7 +104,7 @@ def test_fused_invariance_force_paths(force_path):
 def test_fused_invariance_under_failures():
     cfg = _cfg(n_replicas=4, n_cycles=6)
     d_on = REMDDriver(MDEngine(), cfg, failure_rate=0.4,
-                      telemetry=Telemetry(phase_probe_every=1))
+                      telemetry=Telemetry())
     d_off = REMDDriver(MDEngine(), cfg, failure_rate=0.4)
     d_on.run_fused(d_on.init(), chunk_cycles=3)
     d_off.run_fused(d_off.init(), chunk_cycles=3)
@@ -119,8 +118,7 @@ def test_fused_invariance_under_failures():
 def test_run_invariance(scheme):
     """The legacy per-cycle path honors the same contract."""
     cfg = _cfg(scheme=scheme, n_cycles=5)
-    d_on = REMDDriver(HarmonicEngine(), cfg,
-                      telemetry=Telemetry(phase_probe_every=2))
+    d_on = REMDDriver(HarmonicEngine(), cfg, telemetry=Telemetry())
     d_off = REMDDriver(HarmonicEngine(), cfg)
     d_on.run(d_on.init())
     d_off.run(d_off.init())
@@ -130,8 +128,7 @@ def test_run_invariance(scheme):
 
 def test_sharded_invariance_one_shard():
     cfg = _cfg()
-    d_on = REMDDriver(HarmonicEngine(), cfg,
-                      telemetry=Telemetry(phase_probe_every=1))
+    d_on = REMDDriver(HarmonicEngine(), cfg, telemetry=Telemetry())
     d_off = REMDDriver(HarmonicEngine(), cfg)
     d_on.run_sharded(d_on.init(), mesh=make_replica_mesh(1), chunk_cycles=4)
     d_off.run_sharded(d_off.init(), mesh=make_replica_mesh(1),
@@ -249,7 +246,7 @@ def test_telemetry_off_op_budgets_hold():
 
 def test_report_counters_match_driver_bookkeeping():
     cfg = _cfg(n_cycles=12)
-    tel = Telemetry(phase_probe_every=2)
+    tel = Telemetry()
     d = REMDDriver(HarmonicEngine(), cfg, telemetry=tel)
     d.run_fused(d.init(), chunk_cycles=4)
     r = d.last_report
@@ -266,12 +263,9 @@ def test_report_counters_match_driver_bookkeeping():
     occ = np.asarray(ex["occupancy"])
     np.testing.assert_array_equal(occ.sum(axis=1),
                                   np.full(cfg.n_replicas, 12))
-    # phase probes fired and cover all four phases
-    assert r.phases["samples"] == 2          # chunks 0 and 2 of 3
-    for ph in ("propagate", "features", "exchange", "detect_recover"):
-        assert r.phases["means"][ph] >= 0.0
-    for term, val in r.phases["eq1"].items():
-        assert val >= 0.0, term
+    # host-clock cycle and fetch times, per cycle
+    assert r.phases["t_cycle_mean"] > 0.0
+    assert 0.0 <= r.phases["t_data_mean"] < r.phases["t_cycle_mean"]
     # json round trip + schema
     validate_report(json.loads(r.to_json()))
 
@@ -292,7 +286,7 @@ def test_report_matrix_scheme_has_no_pair_rows():
 def test_telemetry_reset_scopes_counters():
     """reset() after warm-up: counters cover only production cycles."""
     cfg = _cfg(n_cycles=12)
-    tel = Telemetry(phase_probe_every=0)
+    tel = Telemetry()
     d = REMDDriver(HarmonicEngine(), cfg, telemetry=tel)
     ens = d.init()
     ens = d.run_fused(ens, n_cycles=4, chunk_cycles=4)
@@ -315,7 +309,7 @@ def test_report_without_telemetry_still_emitted():
     r = d.last_report
     assert r.cycles == {"total": 4, "counted": 0}
     assert r.exchange["pair_attempt"] is None
-    assert r.phases["samples"] == 0
+    assert set(r.phases) == {"t_cycle_mean", "t_data_mean", "t_prep_mean"}
     validate_report(r.to_dict())
 
 
@@ -327,7 +321,7 @@ def test_report_without_telemetry_still_emitted():
 @multidevice
 def test_wire_ledger_scales_with_invocations():
     cfg = _cfg(n_replicas=8, n_cycles=8)
-    tel = Telemetry(phase_probe_every=0)
+    tel = Telemetry()
     d = REMDDriver(HarmonicEngine(), cfg, telemetry=tel)
     d.run_sharded(d.init(), mesh=make_replica_mesh(8), chunk_cycles=4)
     wire = d.last_report.wire
