@@ -7,7 +7,7 @@ with the force kernels.  Each launch performs ONE fused iteration:
       g  = C @ P_k                   bonded gather      (MXU)
       s  = bonded_scatter_rows(g)    bonded gradients   (VPU)
       fb += s @ P_k^T                bonded scatter     (MXU)
-    nb = nonbonded_pair_rows(C, C)   LJ + elec sweep    (VPU)
+    nb = nonbonded_pair_rows(C, C^T) LJ + elec sweep    (VPU)
     f  = fb + nb_lj + salt * nb_el
     B-A-O-A-B masked update on coordinate/velocity rows 0..2
 
@@ -39,7 +39,8 @@ from repro.kernels.chain_forces.kernel import (_DN, _DNT, N_ROLES,
                                                VMEM_CAP_BYTES,
                                                bonded_scatter_rows,
                                                one_hot_dot)
-from repro.kernels.lj_forces.kernel import nonbonded_pair_rows
+from repro.kernels.lj_forces.kernel import (atom_columns, atom_rows,
+                                           nonbonded_pair_rows, pair_rows)
 
 # Largest system the kernel holds in VMEM: it keeps the whole one-hot
 # matrix, the (Np, Np) exclusion mask and the (Np, Np) pair tile of one
@@ -69,8 +70,9 @@ def _fused_baoab_kernel(c_ref, v_ref, nz_ref, st_ref, bias_ref, p_ref,
         fb = fk if fb is None else fb + fk
 
     # -- force: nonbonded, full (Np, Np) tile ---------------------------
-    rows, _elj, _eel = nonbonded_pair_rows(c, c, m_ref[...],
-                                           coulomb=coulomb)
+    nb, _ = nonbonded_pair_rows(atom_rows(c), atom_columns(c), m_ref[...],
+                                coulomb=coulomb, energies=False)
+    rows = pair_rows(nb)
 
     st = st_ref[0]                                 # (1, 8) step scalars
     trail, lead, salt = st[0, 0], st[0, 1], st[0, 2]

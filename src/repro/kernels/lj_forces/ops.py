@@ -118,18 +118,29 @@ def _pack_nonbonded(pos, lj_sigma, lj_eps, charges, block: int):
     return c, n, n_pad
 
 
+def _nonbonded_launch(pos, lj_sigma, lj_eps, charges, nb_mask, block: int,
+                      interpret: Optional[bool], energies: bool):
+    """Pack, launch the chain nonbonded kernel once, unpack: (f_lj,
+    f_el (R, N, 3)) plus (e_lj, e_el (R,)) when ``energies``."""
+    interp = default_interpret() if interpret is None else interpret
+    c, n, n_pad = _pack_nonbonded(pos, lj_sigma, lj_eps, charges, block)
+    mask = jnp.zeros((n_pad, n_pad), jnp.float32).at[:n, :n].set(nb_mask)
+    out = K.nonbonded_kernel_batched(c, mask, coulomb=ref.COULOMB,
+                                     energies=energies, interpret=interp)
+    f = out[0] if energies else out
+    f_lj = jnp.swapaxes(f[:, 0:3, :n], 1, 2).astype(pos.dtype)
+    f_el = jnp.swapaxes(f[:, 3:6, :n], 1, 2).astype(pos.dtype)
+    if not energies:
+        return f_lj, f_el
+    return f_lj, f_el, out[1][:, 0, 0], out[2][:, 0, 0]
+
+
 def nonbonded_batched(pos, lj_sigma, lj_eps, charges, nb_mask,
                       block: int = 128, interpret: Optional[bool] = None):
     """(R, N, 3) stack through the chain nonbonded kernel: one launch ->
     (f_lj (R, N, 3), f_el (R, N, 3), e_lj (R,), e_el (R,))."""
-    interp = default_interpret() if interpret is None else interpret
-    c, n, n_pad = _pack_nonbonded(pos, lj_sigma, lj_eps, charges, block)
-    mask = jnp.zeros((n_pad, n_pad), jnp.float32).at[:n, :n].set(nb_mask)
-    out, e_lj, e_el = K.nonbonded_kernel_batched(
-        c, mask, coulomb=ref.COULOMB, block=block, interpret=interp)
-    f_lj = jnp.swapaxes(out[:, 0:3, :n], 1, 2).astype(pos.dtype)
-    f_el = jnp.swapaxes(out[:, 3:6, :n], 1, 2).astype(pos.dtype)
-    return f_lj, f_el, e_lj[:, 0, 0], e_el[:, 0, 0]
+    return _nonbonded_launch(pos, lj_sigma, lj_eps, charges, nb_mask,
+                             block, interpret, energies=True)
 
 
 def nonbonded(pos, lj_sigma, lj_eps, charges, nb_mask,
@@ -234,9 +245,8 @@ def nonbonded_force(pos, lj_sigma, lj_eps, charges, nb_mask,
     if not use_kernel:
         return ref.nonbonded_force(pos, lj_sigma, lj_eps, charges, nb_mask,
                                    salt_scale)
-    f_lj, f_el, _, _ = nonbonded_batched(pos, lj_sigma, lj_eps, charges,
-                                         nb_mask, block=block,
-                                         interpret=interpret)
+    f_lj, f_el = _nonbonded_launch(pos, lj_sigma, lj_eps, charges, nb_mask,
+                                   block, interpret, energies=False)
     if salt_scale is not None:
         f_el = salt_scale[..., None, None] * f_el
     return f_lj + f_el

@@ -113,17 +113,48 @@ def test_nonbonded_ref_matches_autodiff():
                                rtol=1e-5, atol=1e-3)
 
 
-def test_nonbonded_kernel_interpret_matches_ref():
+def _oracle64(fn, pos, *args, **kw):
+    """``fn`` (an ``nb_ref`` pass) evaluated in float64: the oracle's own
+    float32 rounding (its rowsum-minus-GEMM force identity cancels at
+    large |x|) stays out of the kernel comparison."""
+    with jax.enable_x64(True):
+        cast = [None if a is None else jnp.asarray(a, jnp.float64)
+                for a in (pos, *args)]
+        kw = {k: None if v is None else jnp.asarray(v, jnp.float64)
+              for k, v in kw.items()}
+        return jax.tree.map(np.asarray, fn(*cast, **kw))
+
+
+@pytest.mark.parametrize("n_atoms,n_rep,block", [
+    (22, 4, 32), (200, 1, 128), (200, 3, 128), (300, 1, 128),
+    (300, 3, 128)])
+@pytest.mark.parametrize("variant", ["energies", "force", "force_salted"])
+def test_nonbonded_kernel_interpret_matches_ref(n_atoms, n_rep, block,
+                                                variant):
     """The chain nonbonded Pallas kernel (interpret) == the jnp oracle:
-    both forces AND both energy accumulators from the one sweep."""
-    sysm, pos = _setup()
+    both forces AND both energy accumulators from the one sweep, or the
+    force-only build through the salt-folded propagate entry, with live
+    lane padding (N not a multiple of 128) and R = 1 or several."""
+    sysm, pos = _setup(n_atoms, n_rep)
     args = (sysm.lj_sigma, sysm.lj_eps, sysm.charges, sysm.nb_mask)
-    ref_out = nb_ref.nonbonded(pos, *args)
-    k_out = nb_ops.nonbonded_batched(pos, *args, block=32, interpret=True)
-    for name, a, b in zip(("f_lj", "f_el", "e_lj", "e_el"), k_out, ref_out):
-        scale = max(float(jnp.max(jnp.abs(b))), 1.0)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-4 * scale, err_msg=name)
+    if variant == "energies":
+        k_out = nb_ops.nonbonded_batched(pos, *args, block=block,
+                                         interpret=True)
+        want = _oracle64(nb_ref.nonbonded, pos, *args)
+        names = ("f_lj", "f_el", "e_lj", "e_el")
+    else:
+        scale = (jnp.linspace(0.6, 1.0, n_rep)
+                 if variant == "force_salted" else None)
+        k_out = (nb_ops.nonbonded_force(pos, *args, salt_scale=scale,
+                                        use_kernel=True, block=block,
+                                        interpret=True),)
+        want = (_oracle64(nb_ref.nonbonded_force, pos, *args,
+                          salt_scale=scale),)
+        names = ("f",)
+    for name, a, b in zip(names, k_out, want):
+        scale = max(float(np.max(np.abs(b))), 1.0)
+        np.testing.assert_allclose(np.asarray(a), b, atol=1e-5 * scale,
+                                   err_msg=name)
 
 
 @pytest.mark.parametrize("salted", [False, True])
@@ -262,6 +293,30 @@ def test_engine_pallas_kernel_propagate_matches_analytic():
     eng_j = MDEngine(force_path="pallas", use_force_kernels=False)
     eng_k = MDEngine(force_path="pallas", use_force_kernels=True)
     state = eng_j.init_state(jax.random.key(0), n)
+    out_j = eng_j.propagate(state, ctrl, n_steps, rngs, max_steps=2)
+    out_k = eng_k.propagate(state, ctrl, n_steps, rngs, max_steps=2)
+    for leaf in ("pos", "vel"):
+        np.testing.assert_allclose(np.asarray(out_k[leaf]),
+                                   np.asarray(out_j[leaf]),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_engine_fused_kernel_propagate_matches_analytic():
+    """MDEngine(force_path="fused") with kernels forced on runs the
+    fused-propagate kernel (interpret), whose nonbonded sweep is the
+    shared pair body on one full tile; it propagates within tolerance
+    of the fused jnp path."""
+    from repro.config import RepExConfig
+    from repro.core import build_grid, ctrl_for_assignment
+    grid = build_grid(RepExConfig(
+        dimensions=(("temperature", 2), ("salt", 2))))
+    n = grid.n_ctrl
+    ctrl = ctrl_for_assignment(grid, jnp.arange(n))
+    rngs = jax.random.split(jax.random.key(6), n)
+    n_steps = jnp.full(n, 2, jnp.int32)
+    eng_j = MDEngine(force_path="fused", use_force_kernels=False)
+    eng_k = MDEngine(force_path="fused", use_force_kernels=True)
+    state = eng_j.init_state(jax.random.key(1), n)
     out_j = eng_j.propagate(state, ctrl, n_steps, rngs, max_steps=2)
     out_k = eng_k.propagate(state, ctrl, n_steps, rngs, max_steps=2)
     for leaf in ("pos", "vel"):

@@ -7,8 +7,8 @@ that overflow VMEM all pass there.  These tests compile each kernel of
 the MD engine for a v5e chip that JAX describes but does not attach, at
 the sizes the engine runs: the main path (``chain_forces`` and the
 dense nonbonded kernel) at the paper's 2,881 atoms and R = 64, the
-fused and sparse kernels at the largest system the engine admits on a
-TPU, and ``exchange_matrix`` at the paper's 1,728 replicas.  Each
+dense, fused and sparse kernels at the largest system the engine admits
+on a TPU, and ``exchange_matrix`` at the paper's 1,728 replicas.  Each
 kernel's custom call carries its ``name``, and the patterns by which the
 benchmark's roofline metrics find the main path's two kernels in a
 device trace match them.
@@ -104,12 +104,27 @@ def test_chain_forces_compiles_v5e_at_limit(one_chip):
         one_chip, *_chain_shapes(CK.MAX_ATOMS, R_MAIN), name="chain_bonded")
 
 
-def test_dense_nonbonded_compiles_v5e(one_chip):
-    """The main path's nonbonded kernel at the paper's 2,881 atoms."""
+@pytest.mark.parametrize("n_replicas", [R_MAIN, 8])
+@pytest.mark.parametrize("energies", [False, True])
+def test_dense_nonbonded_compiles_v5e(one_chip, n_replicas, energies):
+    """The main path's nonbonded kernel at the paper's 2,881 atoms, at
+    the 64-replica block per chip and at an 8-replica block, in its
+    force-only (propagate) and energy-returning builds."""
     n_pad = pad_to_block(N_PAPER, LANE)
     _compile(lambda c, m: LK.nonbonded_kernel_batched(
-        c, m, coulomb=COULOMB, block=LANE, interpret=False),
-        one_chip, ((R_MAIN, 8, n_pad), jnp.float32),
+        c, m, coulomb=COULOMB, energies=energies, interpret=False),
+        one_chip, ((n_replicas, 8, n_pad), jnp.float32),
+        ((n_pad, n_pad), jnp.float32), name="lj_nonbonded_dense")
+
+
+def test_dense_nonbonded_compiles_v5e_at_limit(one_chip):
+    """The largest system the engine admits on the dense path (the
+    bonded kernel's limit): the replica's j planes and the mask slab
+    still fit in VMEM."""
+    n_pad = pad_to_block(CK.MAX_ATOMS, LANE)
+    _compile(lambda c, m: LK.nonbonded_kernel_batched(
+        c, m, coulomb=COULOMB, energies=False, interpret=False),
+        one_chip, ((8, 8, n_pad), jnp.float32),
         ((n_pad, n_pad), jnp.float32), name="lj_nonbonded_dense")
 
 
@@ -172,7 +187,7 @@ def test_roofline_patterns_find_the_named_kernels(one_chip):
     call in the main path's compiled program, and not the other's."""
     def main_path(coords, mask, c, gmat, bond, ang, quad, bias):
         nb = LK.nonbonded_kernel_batched(coords, mask, coulomb=COULOMB,
-                                         block=LANE, interpret=False)
+                                         energies=False, interpret=False)
         bonded = CK.chain_forces_kernel_batched(
             c, gmat, bond, ang, quad, bias, tb=LANE, bias=True,
             interpret=False)
